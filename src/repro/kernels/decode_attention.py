@@ -7,6 +7,10 @@ VMEM, and the (acc, m, l) online-softmax state for all G q-heads of the
 group lives in VMEM scratch. All q-heads of a kv group are processed in one
 tile (G x Dk), so the KV tile is read once per *group*, not per head —
 the GQA bandwidth saving is realised structurally.
+
+The wrapper puts the kv-head axis ahead of the sequence axis, so every
+block's last two dims are (tile, head_dim): the TPU's (8, 128) tiling
+accepts them at any head count.
 """
 from __future__ import annotations
 
@@ -16,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -30,9 +36,9 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, 0, :, :].astype(jnp.float32)  # (G, Dk)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, Dk)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, Dv)
+    q = q_ref[...].astype(jnp.float32)  # (G, Dk)
+    k = k_ref[...].astype(jnp.float32)  # (bk, Dk)
+    v = v_ref[...].astype(jnp.float32)  # (bk, Dv)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, bk)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -55,7 +61,7 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(ki == nk - 1)
     def _fin():
-        o_ref[0, 0, :, :] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -67,8 +73,7 @@ def decode_attention(q, k, v, *, q_offset=0, kv_len=None, window=None,
     Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Hkv
     scale = scale if scale is not None else Dk ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
 
     block_k = min(block_k, Sk)
     nk = -(-Sk // block_k)
@@ -76,8 +81,9 @@ def decode_attention(q, k, v, *, q_offset=0, kv_len=None, window=None,
     if pk:
         k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    qg = q.reshape(B, 1, Hkv, G, Dk).transpose(0, 2, 1, 3, 4).reshape(B, Hkv, G, Dk)[:, :, None]
-    # qg layout: (B, Hkv, 1, G, Dk) so blockspec picks (1,1,1,G,Dk)
+    qg = q.reshape(B, Hkv, G, Dk)  # q-heads h*G..h*G+G-1 share kv head h
+    kt = k.transpose(0, 2, 1, 3)  # (B, Hkv, Sk, Dk)
+    vt = v.transpose(0, 2, 1, 3)
     eff_len = jnp.asarray(Sk if kv_len is None else jnp.minimum(kv_len, Sk))
     meta = jnp.stack([jnp.asarray(q_offset, jnp.int32).reshape(()),
                       eff_len.astype(jnp.int32).reshape(())])
@@ -89,11 +95,11 @@ def decode_attention(q, k, v, *, q_offset=0, kv_len=None, window=None,
         grid=(B, Hkv, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1, G, Dk), lambda b, h, ki: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, Dk), lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, Dv), lambda b, h, ki: (b, ki, h, 0)),
+            pl.BlockSpec((None, None, G, Dk), lambda b, h, ki: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, block_k, Dk), lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((None, None, block_k, Dv), lambda b, h, ki: (b, h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, ki: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, G, Dv), lambda b, h, ki: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((G, Dv), jnp.float32),
@@ -101,5 +107,5 @@ def decode_attention(q, k, v, *, q_offset=0, kv_len=None, window=None,
             pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(meta, qg, k, v)
-    return out.reshape(B, H, Dv)[:, None].transpose(0, 1, 2, 3).reshape(B, 1, H, Dv)
+    )(meta, qg, kt, vt)
+    return out.reshape(B, 1, H, Dv)

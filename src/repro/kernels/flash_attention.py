@@ -14,6 +14,10 @@ TPU-native design notes (vs the CUDA flash-attention the GPU world uses):
     scalars so decode can trace them dynamically.
 
 Supports: causal, sliding window, logit softcap, GQA, q_offset/kv_len.
+
+The wrapper puts the head axis ahead of the sequence axis, so every block's
+last two dims are (tile, head_dim) and meet the TPU's (8, 128) tiling at
+any head count.
 """
 from __future__ import annotations
 
@@ -23,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -38,9 +44,9 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, Dv)
+    q = q_ref[...].astype(jnp.float32)  # (bq, D)
+    k = k_ref[...].astype(jnp.float32)  # (bk, D)
+    v = v_ref[...].astype(jnp.float32)  # (bk, Dv)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (bq, bk)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -67,7 +73,7 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(ki == nk - 1)
     def _fin():
-        o_ref[0, :, 0, :] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -81,8 +87,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Hkv
     scale = scale if scale is not None else Dk ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
 
     block_q = min(block_q, max(Sq, 8))
     block_k = min(block_k, Sk)
@@ -99,6 +104,10 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     meta = jnp.stack([jnp.asarray(q_offset, jnp.int32).reshape(()),
                       eff_len.astype(jnp.int32).reshape(())])
 
+    qt = q.transpose(0, 2, 1, 3)  # (B, H, Sq, Dk)
+    kt = k.transpose(0, 2, 1, 3)  # (B, Hkv, Sk, Dk)
+    vt = v.transpose(0, 2, 1, 3)
+
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, nk=nk)
@@ -108,17 +117,17 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, 1, Dk), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, Dk), lambda b, h, qi, ki, _G=G: (b, ki, h // _G, 0)),
-            pl.BlockSpec((1, block_k, 1, Dv), lambda b, h, qi, ki, _G=G: (b, ki, h // _G, 0)),
+            pl.BlockSpec((None, None, block_q, Dk), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, block_k, Dk), lambda b, h, qi, ki, _G=G: (b, h // _G, ki, 0)),
+            pl.BlockSpec((None, None, block_k, Dv), lambda b, h, qi, ki, _G=G: (b, h // _G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, Dv), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * block_q, H, Dv), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * block_q, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(meta, q, k, v)
-    return out[:, :Sq]
+    )(meta, qt, kt, vt)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -282,8 +282,10 @@ def prefill_group(eng, model: str, pool: _SlotPool,
         from repro.serving import speculative
         speculative.prefill_draft(eng, model, spec, group, prompts, slots, G,
                                   plan_len)
+    t_first = eng._now()
     for seq, tok in zip(group, toks):
         seq.tokens.append(tok)
+        seq.t_first = t_first
         if pp is not None:
             seq.rails += EnergyBreakdown.from_total(
                 pp["energy"] / pp["batch"], pp["rails"])

@@ -61,5 +61,5 @@ def step_bucketed(eng, model: str, temperature: float = 0.0) -> List[Response]:
               if eng.scheduler is not None else EnergyBreakdown())
         eng.ledger.emit("request", dt, eb, model=model, uid=r.uid)
         out.append(Response(r.uid, toks[i, : r.max_new_tokens], dt,
-                            per_req_energy, rails=eb))
+                            per_req_energy, rails=eb, model=model))
     return out

@@ -208,7 +208,9 @@ class ServingEngine:
                          model=seq.model, uid=seq.req.uid)
         out.append(Response(seq.req.uid,
                             np.asarray(seq.tokens[: seq.req.max_new_tokens], np.int32),
-                            latency, energy, rails=seq.rails))
+                            latency, energy, rails=seq.rails,
+                            ttft_s=seq.t_first - seq.req.t_submit,
+                            model=seq.model))
 
     # admission machinery lives in repro.serving.admission
     _validate = staticmethod(adm.validate_request)

@@ -31,7 +31,7 @@ def reject_request(eng, model: str, req: Request, reason: str,
     eng.ledger.emit("rejected", wait, EnergyBreakdown(), t_s=req.t_submit,
                     model=model, uid=req.uid, meta={"error": reason})
     out.append(Response(req.uid, np.zeros(0, np.int32), wait, float("nan"),
-                        error=reason))
+                        error=reason, model=model))
 
 
 def _timeout(eng, model: str, req: Request,

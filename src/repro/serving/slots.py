@@ -42,6 +42,10 @@ class Response:
     # per-rail split of energy_j_pred (attribution from the partition plan's
     # physics fractions); None on the scheduler-less / bucketed-NaN paths
     rails: Optional[EnergyBreakdown] = None
+    # submit to first token on the host, after the token reached it
+    # (continuous mode; None on the bucketed path and for rejections)
+    ttft_s: Optional[float] = None
+    model: str = ""  # the worker that answered
 
 
 class SlotAllocator:
@@ -87,6 +91,7 @@ class _ActiveSeq:
     pos: int  # next cache write position (prompt_len + generated so far)
     model: str = ""  # owning worker (stamped at admission; telemetry key)
     tokens: List[int] = field(default_factory=list)
+    t_first: Optional[float] = None  # engine clock when the first token landed
     # the ONE energy tally: per-rail attribution (plan-derived fractions)
     # whose total_j accumulates the charged step/prefill energies
     rails: EnergyBreakdown = field(default_factory=EnergyBreakdown)

@@ -106,3 +106,24 @@ def test_models_pallas_impl_matches_xla():
     a = attend(q, k, v, causal=True, impl="pallas")
     b = attend(q, k, v, causal=True, impl="xla")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("interpret,backend,expected", [
+    (None, "cpu", True),
+    (None, "tpu", False),
+    (True, "tpu", True),
+    (False, "cpu", False),
+    (True, "gpu", True),
+    (None, "gpu", RuntimeError),
+])
+def test_interpret_mode_follows_caller_then_platform(monkeypatch, interpret,
+                                                     backend, expected):
+    """Interpreted only when asked or on the CPU; an accelerator that is not
+    a TPU is refused rather than run through the interpreter."""
+    import repro.kernels as kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    if expected is RuntimeError:
+        with pytest.raises(RuntimeError, match="gpu"):
+            kernels.interpret_mode(interpret)
+    else:
+        assert kernels.interpret_mode(interpret) is expected
