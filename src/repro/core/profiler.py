@@ -18,6 +18,7 @@ from repro.core.gbdt import GBDTRegressor
 from repro.core.gru import GRUCorrector
 from repro.core.opgraph import OP_TYPES, STATIC_FEATURE_DIM, OpGraph, OpNode
 from repro.core.simulator import PRESETS, DeviceSim, DeviceState
+from repro.core.telemetry import span
 
 FEATURE_DIM = 6 + len(OP_TYPES) + 4
 
@@ -221,10 +222,11 @@ class RuntimeEnergyProfiler:
         return max(lat, 1e-9), max(en, 1e-12)
 
     def _predict_xy(self, X):
-        ce, ct = self._corrections()
-        en = np.maximum(self.energy_model.predict(X) * ce, 1e-12)
-        lat = np.maximum(self.latency_model.predict(X) * ct, 1e-9)
-        return lat, en
+        with span("repro.plan.cost"):  # one batched GBDT pass
+            ce, ct = self._corrections()
+            en = np.maximum(self.energy_model.predict(X) * ce, 1e-12)
+            lat = np.maximum(self.latency_model.predict(X) * ct, 1e-9)
+            return lat, en
 
     def predict_batch(self, items, obs_state):
         """items: list of (op, alpha, prev_alpha). One vectorised GBDT pass —

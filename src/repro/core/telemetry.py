@@ -23,11 +23,18 @@ Conservation is testable: the per-rail components of every breakdown sum to
 the simulator's ground-truth joules (``tests/test_telemetry.py``), and the
 controller, engine and fleet report computed from the same ledger agree
 exactly because they read the same records.
+
+Host time is the other thing the runtime observes: :func:`span` marks a
+stretch of host work with a fixed ``repro.*`` name in the profiler's trace
+(on the device trace's clock), and the ledger's counters say how often each
+layer took its fast or slow path (``docs/serving.md`` §Tracing).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 RAILS = ("cpu", "gpu", "bus")
 
@@ -172,6 +179,14 @@ class EnergyLedger:
         """The per-request accounting stream: one event per served request,
         appended at retirement/completion by the emitting layer."""
         return self.select(kind="request", model=model)
+
+
+def span(name: str) -> TraceAnnotation:
+    """Context manager marking host work as ``name`` in the profiler's host
+    trace. Always on: with no profiler running it costs under a microsecond,
+    so it needs no switch. Names are fixed strings under ``repro.``, with no
+    per-call data in them."""
+    return TraceAnnotation(name)
 
 
 def fold_energy(events: Iterable[StepEvent]) -> EnergyBreakdown:

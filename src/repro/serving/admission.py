@@ -14,7 +14,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.telemetry import EnergyBreakdown
+from repro.core.telemetry import EnergyBreakdown, span
 from repro.serving import planning
 from repro.serving.robustness import reject_request
 from repro.serving.scheduler import AdaOperScheduler
@@ -67,32 +67,33 @@ class AdmissionPolicy:
                wait_s: float, plan_fn=None) -> Tuple[bool, str]:
         """``plan_fn(batch)`` overrides the plan source (the engine passes
         its drift-scoped memo so steady-state decisions cost dict lookups)."""
-        if self.scheduler is None:
-            return True, "no-scheduler"
-        if n_active == 0:
-            return True, "idle-pool"
-        if self.slo_s is not None and wait_s > self.slo_s:
-            return True, "slo-starvation"
-        if plan_fn is None:
-            plan_fn = lambda b: self.scheduler.step_plan(cfg, b, seq_len, max_new)  # noqa: E731
-        cur = plan_fn(n_active)
-        new = plan_fn(n_active + 1)
-        # per-request EDP of one decode step: latency is shared by the actual
-        # batch, energy scales ~linearly with the plan's (bucketed) batch.
-        # With a risk level set, both sides are priced at the same upper
-        # quantile (no systematic bias in the comparison); the SLO check
-        # prices the risk-adjusted latency, so a wide (uncertain) interval
-        # admits more conservatively than a confident one.
-        edp_cur = ((self._risk(cur, "latency") / n_active)
-                   * (self._risk(cur, "energy") / cur["batch"]))
-        edp_new = ((self._risk(new, "latency") / (n_active + 1))
-                   * (self._risk(new, "energy") / new["batch"]))
-        if (self.slo_s is not None
-                and self._risk(new, "latency") * max_new > self.slo_s):
-            return False, "slo-violation"
-        if edp_new <= edp_cur * self.edp_slack:
-            return True, "edp-improves"
-        return False, "edp-worsens"
+        with span("repro.admission.decide"):
+            if self.scheduler is None:
+                return True, "no-scheduler"
+            if n_active == 0:
+                return True, "idle-pool"
+            if self.slo_s is not None and wait_s > self.slo_s:
+                return True, "slo-starvation"
+            if plan_fn is None:
+                plan_fn = lambda b: self.scheduler.step_plan(cfg, b, seq_len, max_new)  # noqa: E731
+            cur = plan_fn(n_active)
+            new = plan_fn(n_active + 1)
+            # per-request EDP of one decode step: latency is shared by the actual
+            # batch, energy scales ~linearly with the plan's (bucketed) batch.
+            # With a risk level set, both sides are priced at the same upper
+            # quantile (no systematic bias in the comparison); the SLO check
+            # prices the risk-adjusted latency, so a wide (uncertain) interval
+            # admits more conservatively than a confident one.
+            edp_cur = ((self._risk(cur, "latency") / n_active)
+                       * (self._risk(cur, "energy") / cur["batch"]))
+            edp_new = ((self._risk(new, "latency") / (n_active + 1))
+                       * (self._risk(new, "energy") / new["batch"]))
+            if (self.slo_s is not None
+                    and self._risk(new, "latency") * max_new > self.slo_s):
+                return False, "slo-violation"
+            if edp_new <= edp_cur * self.edp_slack:
+                return True, "edp-improves"
+            return False, "edp-worsens"
 
     def _record(self, admit: bool, reason: str, n_active: int, uid) -> None:
         self.log.append({"admit": admit, "reason": reason,
@@ -166,47 +167,51 @@ def admit_requests(eng, model: str, pool: _SlotPool, out: List[Response],
     (oversized, missing encoder inputs) is rejected with an error
     ``Response`` and the loop keeps draining — it must not crash the
     serving loop and strand the queue. Returns #admitted."""
-    w, q = eng.workers[model], eng.queues[model]
-    admitted: List[_ActiveSeq] = []
-    while q and pool.alloc.n_free:
-        req = q[0]
-        err = validate_request(w, req)
-        if err is not None:
+    with span("repro.admission.admit"):
+        w, q = eng.workers[model], eng.queues[model]
+        admitted: List[_ActiveSeq] = []
+        while q and pool.alloc.n_free:
+            req = q[0]
+            err = validate_request(w, req)
+            if err is not None:
+                q.pop(0)
+                eng.admission._record(False, f"invalid: {err}",
+                                      len(pool.active), req.uid)
+                reject_request(eng, model, req, err, out)
+                continue
+            seq_len, max_new = eng._plan_shape(pool, extra=req)
+            plan_fn = (None if eng.scheduler is None else
+                       (lambda b: eng._plan_for(model, b, seq_len, max_new)))
+            wait_s = eng._now() - req.t_submit
+            admit, reason = eng.admission.decide(
+                w.cfg, len(pool.active), seq_len, max_new, wait_s,
+                plan_fn=plan_fn)
+            eng.admission._record(admit, reason, len(pool.active), req.uid)
+            if not admit:
+                break
             q.pop(0)
-            eng.admission._record(False, f"invalid: {err}",
-                                  len(pool.active), req.uid)
-            reject_request(eng, model, req, err, out)
-            continue
-        seq_len, max_new = eng._plan_shape(pool, extra=req)
-        plan_fn = (None if eng.scheduler is None else
-                   (lambda b: eng._plan_for(model, b, seq_len, max_new)))
-        admit, reason = eng.admission.decide(
-            w.cfg, len(pool.active), seq_len, max_new,
-            eng._now() - req.t_submit, plan_fn=plan_fn)
-        eng.admission._record(admit, reason, len(pool.active), req.uid)
-        if not admit:
-            break
-        q.pop(0)
-        slot = pool.alloc.alloc()
-        seq = _ActiveSeq(req, slot, pos=len(req.prompt), model=model)
-        # resident immediately so the next decision's plan shape sees it
-        pool.active[slot] = seq
-        admitted.append(seq)
-    if eng.batch_prefill:
-        bucketed = ssm_prompt_bucketed(eng, w)
-        groups: Dict[tuple, List[_ActiveSeq]] = {}
-        for seq in admitted:
-            enc = seq.req.enc_inputs
-            plen = len(seq.req.prompt)
-            key = (AdaOperScheduler._len_bucket(plen) if bucketed else plen,
-                   None if enc is None else enc.shape)
-            groups.setdefault(key, []).append(seq)
-        group_list = list(groups.values())
-    else:
-        group_list = [[seq] for seq in admitted]
-    for group in group_list:
-        prefill_group(eng, model, pool, group, out, temperature)
-    return len(admitted)
+            eng.ledger.count("admitted")
+            eng.ledger.count("queue_wait_us", round(wait_s * 1e6))
+            slot = pool.alloc.alloc()
+            seq = _ActiveSeq(req, slot, pos=len(req.prompt), model=model)
+            # resident immediately so the next decision's plan shape sees it
+            pool.active[slot] = seq
+            admitted.append(seq)
+        if eng.batch_prefill:
+            bucketed = ssm_prompt_bucketed(eng, w)
+            groups: Dict[tuple, List[_ActiveSeq]] = {}
+            for seq in admitted:
+                enc = seq.req.enc_inputs
+                plen = len(seq.req.prompt)
+                key = (AdaOperScheduler._len_bucket(plen) if bucketed else plen,
+                       None if enc is None else enc.shape)
+                groups.setdefault(key, []).append(seq)
+            group_list = list(groups.values())
+        else:
+            group_list = [[seq] for seq in admitted]
+        for group in group_list:
+            prefill_group(eng, model, pool, group, out, temperature)
+        return len(admitted)
 
 
 def prefill_group(eng, model: str, pool: _SlotPool,
@@ -219,81 +224,83 @@ def prefill_group(eng, model: str, pool: _SlotPool,
     per bucket — per-request energy normalised by the plan's bucketed
     batch, the virtual clock advanced by one bucket latency, one
     ``prefill`` StepEvent appended to the ledger."""
-    w = eng.workers[model]
-    G = len(group)
-    b = AdaOperScheduler._new_bucket(G)
-    pad = b - G
-    lens = [len(s.req.prompt) for s in group]
-    plan_len = lens[0]
-    pad_mask = None
-    if ssm_prompt_bucketed(eng, w) and lens:
-        # pow2 prompt-length bucket: LEFT-pad every prompt to the group's
-        # shared bucket with a validity mask (the pad-safe SSM scan leaves
-        # masked positions out of the state entirely, so each row's cache
-        # matches its exact-length prefill); per-seq positions stay the
-        # true prompt lengths.
-        plan_len = AdaOperScheduler._len_bucket(max(lens))
-        if any(n != plan_len for n in lens):
-            padded = np.zeros((G, plan_len), np.int32)
-            mask = np.zeros((G, plan_len), bool)
-            for i, s in enumerate(group):
-                padded[i, plan_len - lens[i]:] = s.req.prompt
-                mask[i, plan_len - lens[i]:] = True
-            prompts = np.concatenate([padded, padded[:1].repeat(pad, 0)]) \
-                if pad else padded
-            pad_mask = np.concatenate([mask, mask[:1].repeat(pad, 0)]) \
-                if pad else mask
-            logits, g_cache = w.prefill_batch(prompts, None,
-                                              pad_mask=pad_mask)
-    if pad_mask is None:
-        prompts = np.stack([s.req.prompt for s in group]
-                           + [group[0].req.prompt] * pad)
-        enc = None
-        if group[0].req.enc_inputs is not None:
-            enc = np.stack([s.req.enc_inputs for s in group]
-                           + [group[0].req.enc_inputs] * pad)
-        logits, g_cache = w.prefill_batch(prompts, enc)
-    slots = np.full(b, pool.alloc.n_slots, np.int32)  # pads drop
-    slots[:G] = [s.slot for s in group]
-    pool.cache = w.write_slots(pool.cache, g_cache, slots)
-    if temperature > 0.0:
-        toks = eng._sample_batch(model, group, logits[:G], temperature)
-    else:
-        toks = [int(t) for t in np.asarray(jnp.argmax(logits[:G], -1))]
-    pp = None
-    if eng.scheduler is not None:
-        # bucketed SSM groups charge the bucket-length plan (same pow2 len
-        # bucket the planner keys on, so exact-length groups are unchanged)
-        pp = eng._prefill_plan_for(model, G, plan_len)
-        eng.scheduler.sim.drain(pp["energy"] * G / pp["batch"])
-        eng.ledger.emit(
-            "prefill", pp["latency"],
-            EnergyBreakdown.from_total(pp["energy"] * G / pp["batch"],
-                                       pp["rails"]),
-            t_s=eng._now(), model=model, n_active=G)
-        # virtual replay charges the whole bucket at the planner's
-        # predicted latency (wall-clock mode measures it)
-        eng._advance_vtime(pp["latency"])
-    spec = getattr(eng, "spec", {}).get(model)
-    if spec is not None:
-        # warm the draft cache for the admitted group (same prompts, the
-        # draft's own params) so verify rounds only catch up 1-2 tokens;
-        # charged as a spec_draft event with the draft plan's rails
-        from repro.serving import speculative
-        speculative.prefill_draft(eng, model, spec, group, prompts, slots, G,
-                                  plan_len)
-    t_first = eng._now()
-    for seq, tok in zip(group, toks):
-        seq.tokens.append(tok)
-        seq.t_first = t_first
-        if pp is not None:
-            seq.rails += EnergyBreakdown.from_total(
-                pp["energy"] / pp["batch"], pp["rails"])
-        pool.tokens[seq.slot, 0] = tok
-        pool.pos[seq.slot] = seq.pos
-        pool.enc_len[seq.slot] = (0 if seq.req.enc_inputs is None
-                                  else seq.req.enc_inputs.shape[0])
-        if len(seq.tokens) >= seq.req.max_new_tokens:
-            eng._retire(pool, seq, out)
-    eng.prefill_batches += 1
-    eng.prefill_batch_requests += G
+    with span("repro.prefill.group"):
+        w = eng.workers[model]
+        G = len(group)
+        b = AdaOperScheduler._new_bucket(G)
+        pad = b - G
+        lens = [len(s.req.prompt) for s in group]
+        plan_len = lens[0]
+        pad_mask = None
+        if ssm_prompt_bucketed(eng, w) and lens:
+            # pow2 prompt-length bucket: LEFT-pad every prompt to the group's
+            # shared bucket with a validity mask (the pad-safe SSM scan leaves
+            # masked positions out of the state entirely, so each row's cache
+            # matches its exact-length prefill); per-seq positions stay the
+            # true prompt lengths.
+            plan_len = AdaOperScheduler._len_bucket(max(lens))
+            if any(n != plan_len for n in lens):
+                padded = np.zeros((G, plan_len), np.int32)
+                mask = np.zeros((G, plan_len), bool)
+                for i, s in enumerate(group):
+                    padded[i, plan_len - lens[i]:] = s.req.prompt
+                    mask[i, plan_len - lens[i]:] = True
+                prompts = np.concatenate([padded, padded[:1].repeat(pad, 0)]) \
+                    if pad else padded
+                pad_mask = np.concatenate([mask, mask[:1].repeat(pad, 0)]) \
+                    if pad else mask
+                logits, g_cache = w.prefill_batch(prompts, None,
+                                                  pad_mask=pad_mask)
+        if pad_mask is None:
+            prompts = np.stack([s.req.prompt for s in group]
+                               + [group[0].req.prompt] * pad)
+            enc = None
+            if group[0].req.enc_inputs is not None:
+                enc = np.stack([s.req.enc_inputs for s in group]
+                               + [group[0].req.enc_inputs] * pad)
+            logits, g_cache = w.prefill_batch(prompts, enc)
+        slots = np.full(b, pool.alloc.n_slots, np.int32)  # pads drop
+        slots[:G] = [s.slot for s in group]
+        pool.cache = w.write_slots(pool.cache, g_cache, slots)
+        with span("repro.prefill.wait"):  # the host waits for the prefill here
+            if temperature > 0.0:
+                toks = eng._sample_batch(model, group, logits[:G], temperature)
+            else:
+                toks = [int(t) for t in np.asarray(jnp.argmax(logits[:G], -1))]
+        pp = None
+        if eng.scheduler is not None:
+            # bucketed SSM groups charge the bucket-length plan (same pow2 len
+            # bucket the planner keys on, so exact-length groups are unchanged)
+            pp = eng._prefill_plan_for(model, G, plan_len)
+            eng.scheduler.sim.drain(pp["energy"] * G / pp["batch"])
+            eng.ledger.emit(
+                "prefill", pp["latency"],
+                EnergyBreakdown.from_total(pp["energy"] * G / pp["batch"],
+                                           pp["rails"]),
+                t_s=eng._now(), model=model, n_active=G)
+            # virtual replay charges the whole bucket at the planner's
+            # predicted latency (wall-clock mode measures it)
+            eng._advance_vtime(pp["latency"])
+        spec = getattr(eng, "spec", {}).get(model)
+        if spec is not None:
+            # warm the draft cache for the admitted group (same prompts, the
+            # draft's own params) so verify rounds only catch up 1-2 tokens;
+            # charged as a spec_draft event with the draft plan's rails
+            from repro.serving import speculative
+            speculative.prefill_draft(eng, model, spec, group, prompts, slots, G,
+                                      plan_len)
+        t_first = eng._now()
+        for seq, tok in zip(group, toks):
+            seq.tokens.append(tok)
+            seq.t_first = t_first
+            if pp is not None:
+                seq.rails += EnergyBreakdown.from_total(
+                    pp["energy"] / pp["batch"], pp["rails"])
+            pool.tokens[seq.slot, 0] = tok
+            pool.pos[seq.slot] = seq.pos
+            pool.enc_len[seq.slot] = (0 if seq.req.enc_inputs is None
+                                      else seq.req.enc_inputs.shape[0])
+            if len(seq.tokens) >= seq.req.max_new_tokens:
+                eng._retire(pool, seq, out)
+        eng.prefill_batches += 1
+        eng.prefill_batch_requests += G

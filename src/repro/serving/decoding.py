@@ -14,7 +14,7 @@ from typing import List
 
 import jax.numpy as jnp
 
-from repro.core.telemetry import EnergyBreakdown
+from repro.core.telemetry import EnergyBreakdown, span
 from repro.serving import speculative
 from repro.serving.slots import Response, _SlotPool
 
@@ -36,43 +36,44 @@ def plain_step(eng, model: str, pool: _SlotPool, out: List[Response],
     """One single-token ragged decode step over the whole slot pool, charged
     once per iteration (the continuous engine's pre-speculation decode body,
     byte-for-byte)."""
-    w = eng.workers[model]
-    enc_len = pool.enc_len if w.cfg.is_encoder_decoder else None
-    next_tok, logits, pool.cache = w.decode_pool(pool.cache, pool.tokens,
-                                                 pool.pos, enc_len=enc_len)
-    n_active = len(pool.active)
-    step_energy = 0.0
-    if eng.scheduler is not None:
-        seq_len, max_new = eng._plan_shape(pool)
-        sp = eng._plan_for(model, n_active, seq_len, max_new)
-        step_energy = sp["step_energy"]
-        eng.scheduler.sim.step(sp["step_latency"])
-        # drain exactly what the resident requests are charged
-        # (step_energy/batch each), so battery drain and summed
-        # per-request energy stay consistent in the fleet report
-        eng.scheduler.sim.drain(step_energy * n_active / sp["batch"])
-        eng.ledger.emit(
-            "decode", sp["step_latency"],
-            EnergyBreakdown.from_total(
-                step_energy * n_active / sp["batch"], sp["rails"]),
-            t_s=t0, model=model, n_active=n_active)
-        eng._advance_vtime(sp["step_latency"])
-    seqs = list(pool.active.values())
-    if temperature > 0.0:
-        # gather active rows on device: the host only ever sees the
-        # sampled tokens, not the whole (max_slots, V) logits
-        rows = logits[jnp.asarray([seq.slot for seq in seqs])]
-        toks = eng._sample_batch(model, seqs, rows, temperature)
-    else:
-        toks = [int(next_tok[seq.slot]) for seq in seqs]
-    for seq, tok in zip(seqs, toks):
-        seq.tokens.append(tok)
-        seq.pos += 1
+    with span("repro.decode.step"):
+        w = eng.workers[model]
+        enc_len = pool.enc_len if w.cfg.is_encoder_decoder else None
+        next_tok, logits, pool.cache = w.decode_pool(pool.cache, pool.tokens,
+                                                     pool.pos, enc_len=enc_len)
+        n_active = len(pool.active)
+        step_energy = 0.0
         if eng.scheduler is not None:
-            # energy of the (bucketed-batch) step plan, shared per slot
-            seq.rails += EnergyBreakdown.from_total(
-                step_energy / sp["batch"], sp["rails"])
-        pool.tokens[seq.slot, 0] = tok
-        pool.pos[seq.slot] = seq.pos
-        if len(seq.tokens) >= seq.req.max_new_tokens:
-            eng._retire(pool, seq, out)
+            seq_len, max_new = eng._plan_shape(pool)
+            sp = eng._plan_for(model, n_active, seq_len, max_new)
+            step_energy = sp["step_energy"]
+            eng.scheduler.sim.step(sp["step_latency"])
+            # drain exactly what the resident requests are charged
+            # (step_energy/batch each), so battery drain and summed
+            # per-request energy stay consistent in the fleet report
+            eng.scheduler.sim.drain(step_energy * n_active / sp["batch"])
+            eng.ledger.emit(
+                "decode", sp["step_latency"],
+                EnergyBreakdown.from_total(
+                    step_energy * n_active / sp["batch"], sp["rails"]),
+                t_s=t0, model=model, n_active=n_active)
+            eng._advance_vtime(sp["step_latency"])
+        seqs = list(pool.active.values())
+        if temperature > 0.0:
+            # gather active rows on device: the host only ever sees the
+            # sampled tokens, not the whole (max_slots, V) logits
+            rows = logits[jnp.asarray([seq.slot for seq in seqs])]
+            toks = eng._sample_batch(model, seqs, rows, temperature)
+        else:
+            toks = [int(next_tok[seq.slot]) for seq in seqs]
+        for seq, tok in zip(seqs, toks):
+            seq.tokens.append(tok)
+            seq.pos += 1
+            if eng.scheduler is not None:
+                # energy of the (bucketed-batch) step plan, shared per slot
+                seq.rails += EnergyBreakdown.from_total(
+                    step_energy / sp["batch"], sp["rails"])
+            pool.tokens[seq.slot, 0] = tok
+            pool.pos[seq.slot] = seq.pos
+            if len(seq.tokens) >= seq.req.max_new_tokens:
+                eng._retire(pool, seq, out)

@@ -27,7 +27,7 @@ from typing import Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.telemetry import EnergyBreakdown, EnergyLedger
+from repro.core.telemetry import EnergyBreakdown, EnergyLedger, span
 from repro.serving import (admission as adm, decoding, planning, robustness,
                            sampling, speculative)
 from repro.serving.admission import AdmissionPolicy  # noqa: F401  (re-export)
@@ -70,11 +70,10 @@ class ServingEngine:
         self.batch_prefill = batch_prefill
         self.prefill_batches = 0
         self.prefill_batch_requests = 0
-        # telemetry spine: the simulator's ledger when a scheduler is attached
+        # telemetry spine: the scheduler's ledger (the simulator's) when a
+        # scheduler is attached, so planner and engine count in one store
         self.ledger: EnergyLedger = (
-            scheduler.sim.ledger
-            if scheduler is not None and hasattr(scheduler.sim, "ledger")
-            else EnergyLedger())
+            scheduler.ledger if scheduler is not None else EnergyLedger())
         # uncertainty knobs (docs/uncertainty.md; defaults inert): risk_level
         # prices admission at an interval upper quantile, legacy_drift pins
         # the fixed hysteresis, ssm_prompt_buckets pow2-pads SSM admission
@@ -233,30 +232,31 @@ class ServingEngine:
         ``check_drift=False`` is for drivers that already ran the per-round
         drift check; ``temperature > 0`` samples each slot from its own
         seed-derived stream."""
-        if check_drift and self.scheduler is not None:
-            self._drift_event()  # direct drivers still invalidate stale plans
-        pool = self._pool(model)
-        out: List[Response] = []
-        # degradation pass first: expired deadlines requeue/error and
-        # battery-critical shedding frees queue space before admission
-        robustness.expire_and_shed(self, model, pool, out)
-        # virtual clock: iterations are timed in _vtime deltas (predicted
-        # latencies), not host speed; wall mode measures wall time
-        t0 = self._now()
-        n_admitted = self._admit(model, pool, out, temperature)
-        if decode and pool.active:
-            # one decode iteration: speculative draft-verify round for
-            # models with a draft attached, the plain ragged step otherwise
-            # (machinery in repro.serving.decoding / .speculative)
-            decoding.decode_round(self, model, pool, out, temperature, t0)
-        if n_admitted or pool.active or out:
-            self.stats[model].append({
-                "mode": "continuous", "active": len(pool.active),
-                "admitted": n_admitted, "retired": len(out),
-                "wall_s": self._now() - t0,
-                "pred_energy_j": float(sum(r.energy_j_pred for r in out))
-                if self.scheduler is not None else float("nan")})
-        return out
+        with span("repro.engine.step"):
+            if check_drift and self.scheduler is not None:
+                self._drift_event()  # direct callers still invalidate stale plans
+            pool = self._pool(model)
+            out: List[Response] = []
+            # degradation pass first: expired deadlines requeue/error and
+            # battery-critical shedding frees queue space before admission
+            robustness.expire_and_shed(self, model, pool, out)
+            # virtual clock: iterations are timed in _vtime deltas (predicted
+            # latencies), not host speed; wall mode measures wall time
+            t0 = self._now()
+            n_admitted = self._admit(model, pool, out, temperature)
+            if decode and pool.active:
+                # one decode iteration: speculative draft-verify round for
+                # models with a draft attached, the plain ragged step otherwise
+                # (machinery in repro.serving.decoding / .speculative)
+                decoding.decode_round(self, model, pool, out, temperature, t0)
+            if n_admitted or pool.active or out:
+                self.stats[model].append({
+                    "mode": "continuous", "active": len(pool.active),
+                    "admitted": n_admitted, "retired": len(out),
+                    "wall_s": self._now() - t0,
+                    "pred_energy_j": float(sum(r.energy_j_pred for r in out))
+                    if self.scheduler is not None else float("nan")})
+            return out
 
     def _serve_round(self, busy: List[str], out: List[Response],
                      temperature: float = 0.0) -> None:
@@ -264,30 +264,31 @@ class ServingEngine:
         co-execution level, run the drift check once, preempt the
         lowest-priority decoding worker on a drift event, then step each
         model at token granularity."""
-        if self.scheduler is not None:
-            self.scheduler.sim.set_coexec(len(busy))
-            # joint planning: the scheduler prices contention per resident
-            # set; its plan caches key on residency, but the engine's memo
-            # does not — clear it when the busy set moves under a coexec
-            # planner (a no-op on the default independent path)
-            if (self.scheduler.set_resident(busy)
-                    and getattr(self.scheduler, "coexec", None) is not None):
-                self._plan_memo.clear()
-        victim = None
-        if self.scheduler is not None and self._drift_event():
-            decoding = [m for m in busy
-                        if m in self.pools and self.pools[m].active]
-            if len(decoding) > 1:
-                # the cached plans just got invalidated: yield the
-                # lowest-priority worker's iteration to the
-                # higher-priority pools while the planner re-solves
-                victim = min(decoding, key=lambda m: (self.priorities[m], m))
-                self.preemptions[victim] += 1
-                self.ledger.count("preemptions")
-        for m in busy:
-            out.extend(self.step_continuous(m, decode=(m != victim),
-                                            check_drift=False,
-                                            temperature=temperature))
+        with span("repro.engine.round"):
+            if self.scheduler is not None:
+                self.scheduler.sim.set_coexec(len(busy))
+                # joint planning: the scheduler prices contention per resident
+                # set; its plan caches key on residency, but the engine's memo
+                # does not — clear it when the busy set moves under a coexec
+                # planner (a no-op on the default independent path)
+                if (self.scheduler.set_resident(busy)
+                        and getattr(self.scheduler, "coexec", None) is not None):
+                    self._plan_memo.clear()
+            victim = None
+            if self.scheduler is not None and self._drift_event():
+                decoding = [m for m in busy
+                            if m in self.pools and self.pools[m].active]
+                if len(decoding) > 1:
+                    # the cached plans just got invalidated: yield the
+                    # lowest-priority worker's iteration to the
+                    # higher-priority pools while the planner re-solves
+                    victim = min(decoding, key=lambda m: (self.priorities[m], m))
+                    self.preemptions[victim] += 1
+                    self.ledger.count("preemptions")
+            for m in busy:
+                out.extend(self.step_continuous(m, decode=(m != victim),
+                                                check_drift=False,
+                                                temperature=temperature))
 
     def run_all(self, temperature: float = 0.0) -> List[Response]:
         """Round-robin across models until all queues drain (the paper's

@@ -17,6 +17,7 @@ scheduler's plan object unchanged, bit-identically.
 """
 from __future__ import annotations
 
+from repro.core.telemetry import span
 from repro.sharding import comm
 
 # hysteresis thresholds for drift events, sized ~4 sigma above the resource
@@ -53,6 +54,21 @@ def expected_tokens(alpha: float, k: int) -> float:
     return 1.0 + sum(a ** i for i in range(1, int(k) + 1))
 
 
+def _memo(eng, name: str, key, solve):
+    """The plan memoised under ``key``, else ``solve()``'s, stored there.
+    The lookup runs inside the span ``name``; the ledger counts every lookup
+    (``plan_lookups``) and every hit (``plan_memo_hits``), and the scheduler
+    counts what each miss found in its own cache."""
+    with span(name):
+        eng.ledger.count("plan_lookups")
+        plan = eng._plan_memo.get(key)
+        if plan is None:
+            plan = eng._plan_memo[key] = solve()
+        else:
+            eng.ledger.count("plan_memo_hits")
+        return plan
+
+
 def spec_plan_for(eng, model: str, batch: int, seq_len: int, max_new: int):
     """Speculation pricing served from the drift-scoped memo: the target's
     base decode-step plan plus the draft worker's own step plan (each
@@ -63,16 +79,15 @@ def spec_plan_for(eng, model: str, batch: int, seq_len: int, max_new: int):
     sch = eng.scheduler
     key = ("spec", model, sch._new_bucket(batch), sch._len_bucket(seq_len),
            sch._new_bucket(max_new))
-    draft = eng._plan_memo.get(key)
-    if draft is None:
+
+    def solve():
         spec = eng.spec[model]
         w = eng.workers[model]
         draft = sch.step_plan(spec.worker.cfg, batch, seq_len, max_new)
-        draft = comm.shard_plan(
+        return comm.shard_plan(
             draft, comm.comm_term(spec.worker.cfg, w.ctx, draft["batch"], 1),
             "step_energy", "step_latency")
-        eng._plan_memo[key] = draft
-    return {"base": base, "draft": draft}
+    return {"base": base, "draft": _memo(eng, "repro.plan.step", key, solve)}
 
 
 def draft_prefill_plan_for(eng, model: str, batch: int, prompt_len: int):
@@ -80,17 +95,16 @@ def draft_prefill_plan_for(eng, model: str, batch: int, prompt_len: int):
     warmed at admission so verify rounds only ever catch up 1–2 tokens)."""
     sch = eng.scheduler
     key = ("dpre", model, sch._new_bucket(batch), sch._len_bucket(prompt_len))
-    plan = eng._plan_memo.get(key)
-    if plan is None:
+
+    def solve():
         spec = eng.spec[model]
         w = eng.workers[model]
         plan = sch.prefill_plan(spec.worker.cfg, batch, prompt_len)
-        plan = comm.shard_plan(
+        return comm.shard_plan(
             plan, comm.comm_term(spec.worker.cfg, w.ctx, plan["batch"],
                                  sch._len_bucket(prompt_len)),
             "energy", "latency")
-        eng._plan_memo[key] = plan
-    return plan
+    return _memo(eng, "repro.plan.prefill", key, solve)
 
 
 def step_plan_for(eng, model: str, batch: int, seq_len: int, max_new: int):
@@ -98,16 +112,15 @@ def step_plan_for(eng, model: str, batch: int, seq_len: int, max_new: int):
     sch = eng.scheduler
     key = (model, sch._new_bucket(batch), sch._len_bucket(seq_len),
            sch._new_bucket(max_new))
-    plan = eng._plan_memo.get(key)
-    if plan is None:
+
+    def solve():
         w = eng.workers[model]
         plan = sch.step_plan(w.cfg, batch, seq_len, max_new)
         # one decode step moves (bucketed-batch, 1 token) of activations
-        plan = comm.shard_plan(
+        return comm.shard_plan(
             plan, comm.comm_term(w.cfg, w.ctx, plan["batch"], 1),
             "step_energy", "step_latency")
-        eng._plan_memo[key] = plan
-    return plan
+    return _memo(eng, "repro.plan.step", key, solve)
 
 
 def prefill_plan_for(eng, model: str, batch: int, prompt_len: int):
@@ -115,16 +128,15 @@ def prefill_plan_for(eng, model: str, batch: int, prompt_len: int):
     batched admission path charges one bucketed-batch plan per group."""
     sch = eng.scheduler
     key = ("pre", model, sch._new_bucket(batch), sch._len_bucket(prompt_len))
-    plan = eng._plan_memo.get(key)
-    if plan is None:
+
+    def solve():
         w = eng.workers[model]
         plan = sch.prefill_plan(w.cfg, batch, prompt_len)
-        plan = comm.shard_plan(
+        return comm.shard_plan(
             plan, comm.comm_term(w.cfg, w.ctx, plan["batch"],
                                  sch._len_bucket(prompt_len)),
             "energy", "latency")
-        eng._plan_memo[key] = plan
-    return plan
+    return _memo(eng, "repro.plan.prefill", key, solve)
 
 
 def _interval_exit(eng, obs) -> bool:
@@ -149,14 +161,23 @@ def _interval_exit(eng, obs) -> bool:
 def drift_event(eng) -> bool:
     """Compare the observed device state / profiler version against the
     last planning reference; on a drift event the step-plan memo is
-    invalidated and the ledger's ``engine_drift_events`` counter bumps.
+    invalidated and the ledger's ``engine_drift_events`` counter bumps,
+    with one counter for its cause: ``drift_by_version`` (a profiler
+    correction), ``drift_by_epoch`` (a fault or recovery), else
+    ``drift_by_state`` (the device state moved past the hysteresis).
 
     With an uncertainty model attached to the profiler (and the engine not
     pinned to ``legacy_drift``), the fixed state hysteresis is replaced by
     the calibrated-interval check: a drift event fires when re-pricing a
     memoised plan under the current state escapes the interval it was
-    stamped with (counted as ``interval_repartitions``), or on the usual
-    correction-version / fault-epoch moves."""
+    stamped with (counted as ``interval_repartitions`` in place of
+    ``drift_by_state``), or on the usual correction-version / fault-epoch
+    moves."""
+    with span("repro.plan.drift"):
+        return _drift_event(eng)
+
+
+def _drift_event(eng) -> bool:
     sch = eng.scheduler
     obs = sch.sim.observe()
     ver = sch.profiler.correction_version()
@@ -183,8 +204,14 @@ def drift_event(eng) -> bool:
     if event:
         eng.drift_events += 1
         eng.ledger.count("engine_drift_events")
-        if interval_exit:
+        if ver != rver:
+            eng.ledger.count("drift_by_version")
+        elif epoch != repoch:
+            eng.ledger.count("drift_by_epoch")
+        elif interval_exit:
             eng.ledger.count("interval_repartitions")
+        else:
+            eng.ledger.count("drift_by_state")
         eng._plan_memo.clear()
     else:
         eng._drift_ref = ref  # keep the reference until a real move

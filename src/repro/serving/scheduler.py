@@ -18,6 +18,7 @@ from repro.core.coexec import FULL_DUTY, CoexecPlanner
 from repro.core.opgraph import build_transformer_graph
 from repro.core.partitioner import dp_partition, score_plan
 from repro.core.profiler import state_bucket
+from repro.core.telemetry import EnergyLedger, span
 from repro.faults.recovery import pinned_partition, surviving_alpha
 
 
@@ -68,8 +69,10 @@ class AdaOperScheduler:
         self._resident: tuple = ()
         self._graph_cache: OrderedDict = OrderedDict()
         self._plan_cache: OrderedDict = OrderedDict()
-        self.plan_cache_hits = 0
-        self.plan_cache_misses = 0
+        # the simulator's ledger where it has one: the plan cache counts its
+        # hits and misses there, beside the engine's counters
+        ledger = getattr(sim, "ledger", None)
+        self.ledger: EnergyLedger = EnergyLedger() if ledger is None else ledger
 
     def set_resident(self, models) -> bool:
         """Declare the currently-busy worker set (the engine calls this each
@@ -164,39 +167,40 @@ class AdaOperScheduler:
         key = (cfg.name, b, seq, kind) + cache_key + joint_key
         ent = self._plan_cache.get(key)
         if ent is not None:
-            self.plan_cache_hits += 1
+            self.ledger.count("plan_cache_hits")
             self._plan_cache.move_to_end(key)
             return ent
-        self.plan_cache_misses += 1
-        g = self._graph(cfg, b, seq, kind)
-        pinned = (surviving_alpha(self.sim)
-                  if getattr(self.sim, "faulted_rails", None) else None)
-        if pinned is None:
-            ent = dp_partition(g, joint_cost, objective=self.objective)
-            if joint_cost is not cost_fn:
-                # contention priced the search; the accounting (admission,
-                # EDP scoring, ledger charges) stays on the base predictor
-                ent = score_plan(g, ent.alphas, cost_fn)
-        else:
-            # processor fallback: a rail is down, pin every op to the
-            # survivor (cache-scoped to the fault epoch via cache_key)
-            ent = pinned_partition(g, cost_fn, pinned)
-        ent.rail_fractions = (self.sim.rail_fractions(g, ent.alphas)
-                              if hasattr(self.sim, "rail_fractions") else None)
-        # risk-aware serving (repro.uncertainty): fresh solves are stamped
-        # with their calibrated (latency, energy) prediction interval so
-        # admission can price an upper quantile and the engine can trigger
-        # repartition on interval exit. None (no uncertainty model attached,
-        # or a bare cost callable) is the bit-identical inert default.
-        ent.interval = (cost_fn.plan_interval(g, ent.alphas)
-                        if getattr(self.profiler, "uncertainty", None)
-                        is not None and hasattr(cost_fn, "plan_interval")
-                        else None)
-        ent.graph = g
-        self._plan_cache[key] = ent
-        while len(self._plan_cache) > self.plan_cache_size:
-            self._plan_cache.popitem(last=False)
-        return ent
+        self.ledger.count("plan_cache_misses")
+        with span("repro.plan.solve"):  # graph, DP, rail fractions, interval
+            g = self._graph(cfg, b, seq, kind)
+            pinned = (surviving_alpha(self.sim)
+                      if getattr(self.sim, "faulted_rails", None) else None)
+            if pinned is None:
+                ent = dp_partition(g, joint_cost, objective=self.objective)
+                if joint_cost is not cost_fn:
+                    # contention priced the search; the accounting (admission,
+                    # EDP scoring, ledger charges) stays on the base predictor
+                    ent = score_plan(g, ent.alphas, cost_fn)
+            else:
+                # processor fallback: a rail is down, pin every op to the
+                # survivor (cache-scoped to the fault epoch via cache_key)
+                ent = pinned_partition(g, cost_fn, pinned)
+            ent.rail_fractions = (self.sim.rail_fractions(g, ent.alphas)
+                                  if hasattr(self.sim, "rail_fractions") else None)
+            # risk-aware serving (repro.uncertainty): fresh solves are stamped
+            # with their calibrated (latency, energy) prediction interval so
+            # admission can price an upper quantile and the engine can trigger
+            # repartition on interval exit. None (no uncertainty model attached,
+            # or a bare cost callable) is the bit-identical inert default.
+            ent.interval = (cost_fn.plan_interval(g, ent.alphas)
+                            if getattr(self.profiler, "uncertainty", None)
+                            is not None and hasattr(cost_fn, "plan_interval")
+                            else None)
+            ent.graph = g
+            self._plan_cache[key] = ent
+            while len(self._plan_cache) > self.plan_cache_size:
+                self._plan_cache.popitem(last=False)
+            return ent
 
     def _plan_pair(self, cfg, b: int, plen: int, max_new: int, cost_fn, cache_key):
         return (self._plan_one(cfg, b, plen, "prefill", cost_fn, cache_key),

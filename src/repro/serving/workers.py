@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import span
 from repro.models import model as model_lib
 from repro.serving.sampling import _sample_rows
 from repro.sharding.context import ExecContext
@@ -218,8 +219,9 @@ class ModelWorker:
             self.params, pool_cache, jnp.asarray(tokens),
             jnp.asarray(pos, dtype=jnp.int32),
             None if enc_len is None else jnp.asarray(enc_len, dtype=jnp.int32))
-        return (np.asarray(jnp.argmax(logits, -1).astype(jnp.int32)),
-                logits, pool_cache)
+        with span("repro.decode.wait"):  # the host waits for the step here
+            next_tok = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+        return next_tok, logits, pool_cache
 
     def decode_verify(self, pool_cache, tokens: np.ndarray, pos: np.ndarray):
         """Multi-position ragged decode over the slot pool — the speculative

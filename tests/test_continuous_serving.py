@@ -330,10 +330,10 @@ def test_step_plan_is_bucketed_and_cached(sched, tiny):
     fixed = AdaOperScheduler(sched.profiler, _FixedSim())
     p5 = fixed.step_plan(cfg, 5, 20, 6)
     assert p5["batch"] == 8  # pow2 batch bucket
-    h0 = fixed.plan_cache_hits
+    h0 = fixed.ledger.counters.get("plan_cache_hits", 0)
     p6 = fixed.step_plan(cfg, 6, 20, 5)  # same (batch, seq, horizon) buckets
     assert p6["batch"] == 8
-    assert fixed.plan_cache_hits > h0
+    assert fixed.ledger.counters.get("plan_cache_hits", 0) > h0
     assert p6["step_latency"] == p5["step_latency"]
 
 
