@@ -3,6 +3,12 @@
 AdaOper's offline energy model: squared-loss boosting over histogram-binned
 features (quantile bins, exact greedy split on bins). Small and fast enough
 to refit on-device; no external ML deps.
+
+``GBDTRegressor.predict`` evaluates the whole ensemble once per distinct
+binned row: the trees are packed into flat node arrays after a fit, and all
+of them are walked together. The leaf values are summed in tree order from
+the base score, so the result is bit-identical to boosting's own per-tree
+evaluation (``_Tree.predict``, kept for ``fit`` and as the reference).
 """
 from __future__ import annotations
 
@@ -101,6 +107,44 @@ class _Tree:
         return self._val[nid]
 
 
+class _PackedTrees:
+    """A fitted ensemble as ``(T, max_nodes)`` node arrays, flattened.
+
+    Node ids are global (``t * max_nodes + local``). ``fit`` appends a
+    split's two children together, so a row at node ``n`` steps to
+    ``left[n] + (x[feat[n]] > thr[n])``. A leaf, and any padding past a
+    tree's last node, points to itself with the top bin as threshold, so
+    every row can take ``max_depth`` steps and stay on its leaf after.
+    """
+
+    def __init__(self, trees: List[_Tree]):
+        T = len(trees)
+        M = max((len(t.nodes) for t in trees), default=1)
+        feat = np.zeros((T, M), np.intp)
+        thr = np.full((T, M), 255, np.uint8)
+        left = np.tile(np.arange(M, dtype=np.intp), (T, 1))
+        val = np.zeros((T, M))
+        for t, tree in enumerate(trees):
+            for i, nd in enumerate(tree.nodes):
+                val[t, i] = nd.value
+                if not nd.is_leaf:  # right == left + 1, as fit appends them
+                    feat[t, i], thr[t, i], left[t, i] = nd.feature, nd.threshold_bin, nd.left
+        self.roots = (np.arange(T, dtype=np.intp) * M)[:, None]
+        self.feat, self.thr, self.val = feat.ravel(), thr.ravel(), val.ravel()
+        self.left = (left + self.roots).ravel()
+        self.depth = max((t.max_depth for t in trees), default=0)
+
+    def leaf_values(self, Xb: np.ndarray) -> np.ndarray:
+        """(T, N) leaf value of every tree for every binned row of ``Xb``."""
+        N, F = Xb.shape
+        flat = Xb.ravel()
+        row = (np.arange(N, dtype=np.intp) * F)[None, :]
+        nid = np.broadcast_to(self.roots, (len(self.roots), N))
+        for _ in range(self.depth):
+            nid = self.left[nid] + (flat[row + self.feat[nid]] > self.thr[nid])
+        return self.val[nid]
+
+
 @dataclass
 class GBDTRegressor:
     n_estimators: int = 120
@@ -116,10 +160,16 @@ class GBDTRegressor:
     # traversal over its batch). Planner caches are verified against this —
     # a warm-cache schedule decision must not touch the trees at all.
     n_predict_calls: int = 0
+    # rows predict() was asked for, and the distinct binned rows it walked
+    n_predict_rows: int = 0
+    n_predict_unique_rows: int = 0
 
     _bin_edges: Optional[np.ndarray] = None
     _trees: List[_Tree] = field(default_factory=list)
     _base: float = 0.0
+    # every tree in flat arrays, built on the first predict after a fit
+    _packed: Optional[_PackedTrees] = field(default=None, repr=False,
+                                            compare=False)
 
     # ----- binning -----
     def _fit_bins(self, X):
@@ -146,6 +196,7 @@ class GBDTRegressor:
         X = np.asarray(X, np.float64)
         y = self._tx(np.asarray(y, np.float64))
         rng = np.random.default_rng(self.seed)
+        self._packed = None
         self._fit_bins(X)
         Xb = self._bin(X)
         self._base = float(y.mean())
@@ -165,12 +216,21 @@ class GBDTRegressor:
 
     def predict(self, X: np.ndarray) -> np.ndarray:
         self.n_predict_calls += 1
-        X = np.asarray(X, np.float64)
-        Xb = self._bin(X)
-        pred = np.full(Xb.shape[0], self._base)
-        for t in self._trees:
-            pred += self.learning_rate * t.predict(Xb)
-        return self._itx(pred)
+        Xb = self._bin(np.asarray(X, np.float64))
+        # partitioner tables repeat rows (repeated layers, constant state
+        # columns); after binning few distinct rows remain
+        rows = Xb.view(np.dtype((np.void, Xb.shape[1]))).ravel()
+        _, first, inverse = np.unique(rows, return_index=True,
+                                      return_inverse=True)
+        self.n_predict_rows += Xb.shape[0]
+        self.n_predict_unique_rows += len(first)
+        if self._packed is None:
+            self._packed = _PackedTrees(self._trees)
+        leaf = self._packed.leaf_values(Xb[first])  # (T, unique rows)
+        pred = np.full(len(first), self._base)
+        for v in self.learning_rate * leaf:  # tree order, as fit adds them
+            pred += v
+        return self._itx(pred)[inverse.ravel()]
 
     def score_rmse(self, X, y) -> float:
         p = self.predict(X)

@@ -171,6 +171,7 @@ class AdaOperScheduler:
             self._plan_cache.move_to_end(key)
             return ent
         self.ledger.count("plan_cache_misses")
+        rows0, unique0 = self._cost_rows()
         with span("repro.plan.solve"):  # graph, DP, rail fractions, interval
             g = self._graph(cfg, b, seq, kind)
             pinned = (surviving_alpha(self.sim)
@@ -197,10 +198,19 @@ class AdaOperScheduler:
                             is not None and hasattr(cost_fn, "plan_interval")
                             else None)
             ent.graph = g
+            rows, unique = self._cost_rows()
+            self.ledger.count("plan_cost_rows", rows - rows0)
+            self.ledger.count("plan_cost_unique_rows", unique - unique0)
             self._plan_cache[key] = ent
             while len(self._plan_cache) > self.plan_cache_size:
                 self._plan_cache.popitem(last=False)
             return ent
+
+    def _cost_rows(self) -> Tuple[int, int]:
+        """Rows the energy model's passes were asked for so far, and the
+        distinct binned rows they walked; the latency model sees the same."""
+        em = self.profiler.energy_model
+        return em.n_predict_rows, em.n_predict_unique_rows
 
     def _plan_pair(self, cfg, b: int, plen: int, max_new: int, cost_fn, cache_key):
         return (self._plan_one(cfg, b, plen, "prefill", cost_fn, cache_key),
