@@ -2,19 +2,21 @@
 
 Once the window has closed and the program's state is freed, a sample of the
 requests each resident finished, drawn from the seed and holding its longest,
-is run through the plain float32 reference (``bench/reference``) with the
-prompt and the served tokens. At each served position the number read is the
-gap by which the served token's reference logit lies below the reference's
+is run through the plain float32 reference of its architecture
+(``bench/reference/<arch>.py``, found by ``bench/arch.py``) with the prompt
+and the served tokens. At each served position the number read is the gap by
+which the served token's reference logit lies below the reference's
 best logit there; for greedy decoding it is 0 where the program agrees, and
 small where a near tie flipped on rounding. The number compared, per resident,
 is the widest such gap; its limit is the configuration's ``limits.served_gap``.
 """
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from bench import arch
 
 SAMPLE_TOKENS = 256  # served tokens to compare per resident, at the least
 
@@ -48,8 +50,7 @@ def _sequences(picked):
 
 
 def reference(conf: dict, seed: int, quant: Optional[str] = None):
-    mod = importlib.import_module(f"bench.reference.{conf['reference']}")
-    return mod.Reference(conf["model"], seed, quant)
+    return arch.parts(conf).reference(conf["model"], seed, quant)
 
 
 def served_gaps(conf: dict, seed: int, picked: List) -> np.ndarray:
@@ -65,7 +66,7 @@ def served_gaps(conf: dict, seed: int, picked: List) -> np.ndarray:
 
 def control_gaps(conf: dict, seed: int, picked: List, quant: str) -> np.ndarray:
     """The control: at the same positions of the same sequences, the gap of
-    the token that the weights rounded to ``quant`` put first."""
+    the token that the reference computed in ``quant`` puts first."""
     seqs, rows, toks = _sequences(picked)
     low = reference(conf, seed, quant).score(seqs, rows, toks)
     res = reference(conf, seed).score(seqs, rows, [am for _, _, am in low])

@@ -7,9 +7,10 @@ For each seed, in one process: serve the cell's mix for ``--seconds`` at its
 own load, and judge the window's requests twice through ``check.verdict``:
 once with the served tokens (the program's reading, the lower end of a limit,
 which has to come out correct), once with the control's tokens at the same
-positions, the reference with its weights rounded to fp8 put in the program's
-place (the upper end, which has to come out not correct). One JSON line per
-seed. The benchmark's own runs never run this.
+positions, the reference computed in fp8 (weights and the activations that
+meet them) put in the program's place (the upper end, which has to come out
+not correct). One JSON line per seed. The benchmark's own runs never run
+this.
 """
 from __future__ import annotations
 

@@ -4,11 +4,13 @@ What the algorithm needs, not what the program happens to do: attention over
 the true context of each sequence (not the whole ``max_len`` cache), the LM
 head at the last prompt position only, the vocabulary as configured (not its
 padding), and bf16 weights and KV (2 bytes) with float32 norm scales and
-biases. ``m`` is a configuration's ``model`` block (``bench/configs``).
+biases. ``m`` is a configuration's ``model`` block (``bench/configs``);
+``counters`` are the program's counters for the resident over the window,
+which a dense model's costs do not depend on.
 """
 from __future__ import annotations
 
-from typing import Iterable
+from typing import Dict, Iterable
 
 
 def _widths(m: dict):
@@ -56,7 +58,7 @@ def _token_flops(m: dict, context: int, lm_head: bool) -> int:
             + (2 * d * v if lm_head else 0))
 
 
-def decode_step(m: dict, positions: Iterable[int]):
+def decode_step(m: dict, positions: Iterable[int], counters: Dict[str, int]):
     """(flops, bytes) of one decode step for the active sequences, each
     writing its new token at ``pos`` and attending over ``pos + 1`` keys."""
     positions = list(positions)
@@ -66,7 +68,7 @@ def decode_step(m: dict, positions: Iterable[int]):
     return flops, nbytes
 
 
-def prefill(m: dict, lengths: Iterable[int]):
+def prefill(m: dict, lengths: Iterable[int], counters: Dict[str, int]):
     """(flops, bytes) of prefilling prompts of ``lengths``: causal attention,
     the LM head at the last position, KV written for every position."""
     lengths = list(lengths)

@@ -2,12 +2,13 @@
 
 The engine is built as ``repro.launch.serve.serve`` builds it (DeviceSim
 ``moderate``, the GBDT profiler calibrated offline, ``AdaOperScheduler``,
-continuous mode), with the benchmark's seeded weights. The harness owns the
-loop that ``ServingEngine.run_all`` would run: it submits each request when it
-falls due, with ``t_submit`` set to its due time, calls the engine's round for
-the busy models, and notes the host time at which each sequence's token count
-grows. Nothing of the program is edited; the timing hooks are wrappers set on
-the engine's and workers' instances.
+continuous mode), with the benchmark's seeded weights, made by the weights
+module of each configuration's architecture (``bench/arch.py``). The harness
+owns the loop that ``ServingEngine.run_all`` would run: it submits each
+request when it falls due, with ``t_submit`` set to its due time, calls the
+engine's round for the busy models, and notes the host time at which each
+sequence's token count grows. Nothing of the program is edited; the timing
+hooks are wrappers set on the engine's and workers' instances.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import traffic, weights
+from bench import arch, traffic
 
 BENCH_DIR = Path(__file__).resolve().parent
 CONFIG_DIR = BENCH_DIR / "configs"
@@ -82,6 +83,7 @@ class Cell:
         self.names = traffic.residents(mix, cell_config)
         self.confs = {n: load_config(n) for n in self.names}
         self.cfgs = {n: model_config(c) for n, c in self.confs.items()}
+        self.parts = {n: arch.parts(c) for n, c in self.confs.items()}
         # the simulated phone the planner prices against is part of the
         # deployment, not of the traffic: its seed is fixed
         t = time.time()
@@ -95,7 +97,7 @@ class Cell:
         _log(f"planner calibrated in {time.time() - t:.1f} s")
         for n in self.names:
             t = time.time()
-            params = weights.served_params(seed, self.confs[n]["model"])
+            params = self.parts[n].weights.served_params(seed, self.confs[n]["model"])
             self._check_layout(n, params)
             self.eng.add_model(n, self.cfgs[n], params, max_len=mix["max_len"])
             jax.block_until_ready(params)
@@ -113,7 +115,7 @@ class Cell:
         for n in self.names:
             w = self.eng.workers[n]
             w.params = None
-            w.params = weights.served_params(seed, self.confs[n]["model"])
+            w.params = self.parts[n].weights.served_params(seed, self.confs[n]["model"])
             self.eng.queues[n].clear()
             self.eng.pools.pop(n, None)
             jax.block_until_ready(self.eng._pool(n).cache)
@@ -229,8 +231,13 @@ class Cell:
         self.decode_calls.clear()
 
     def counters(self) -> dict:
+        """The engine's counters and every counter of the program's ledger
+        (``eng.ledger.counters``); a ledger counter appears once first
+        counted, so a delta over the window reads an absent one as 0. Where
+        both count under one name, the engine's attribute is kept."""
         eng = self.eng
-        return {"prefill_batches": eng.prefill_batches,
+        return {**eng.ledger.counters,
+                "prefill_batches": eng.prefill_batches,
                 "prefill_batch_requests": eng.prefill_batch_requests,
                 "preemptions": sum(eng.preemptions.values()),
                 "drift_events": eng.drift_events,

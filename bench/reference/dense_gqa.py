@@ -6,15 +6,17 @@ plain scale), grouped-query attention with optional QKV bias and rotary
 embeddings (half-split layout, ``theta`` from the configuration), a causal
 softmax, the output projection and a residual add; then an RMSNorm and a
 SwiGLU MLP with a residual add; a final RMSNorm and the LM head (the tied
-embedding or its own matrix). Weights come from ``bench.weights`` with the
-run's seed and are upcast to float32; matmuls run at the highest precision.
+embedding or its own matrix). Weights come from ``bench/weights/dense_gqa.py``
+with the run's seed and are upcast to float32; matmuls run at the highest
+precision.
 
 The model is run layer by layer over a few sequences at a time, so that it
 fits beside nothing: it runs after the program's state is freed.
 
-``quant`` (the control) rounds every matrix weight to the precision below the
-configuration's bf16 before use: ``fp8`` (e4m3), with one scale per output
-column.
+``quant`` (the control) computes every product with a weight matrix in the
+precision below the configuration's bf16: ``fp8`` (e4m3), the weight with one
+scale per output column and the activations entering it with one scale per
+row, accumulated in float32, as an fp8 matmul would.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench.weights import dense_gqa as weights
 
 EPS = 1e-6
 _MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head")
@@ -39,6 +41,12 @@ def quantize(w, quant: Optional[str]):
         raise ValueError(f"unknown control precision {quant!r}")
     s = jnp.maximum(jnp.max(jnp.abs(w), axis=0, keepdims=True), 1e-30) / 448.0
     return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+
+
+def _mm(x, w, quant: Optional[str]):
+    """``x @ w``; under the control, ``x`` is first rounded to ``quant`` row by
+    row (``w`` was rounded when its layer was made)."""
+    return quantize(x.T, quant).T @ w if quant else x @ w
 
 
 def _f32(tree, quant):
@@ -67,13 +75,13 @@ def rope(x, theta: float):
                             x2 * jnp.cos(ang) + x1 * jnp.sin(ang)], axis=-1)
 
 
-def block(m: dict, p: dict, x):
+def block(m: dict, p: dict, x, quant: Optional[str] = None):
     """One decoder layer over one sequence x (S, d)."""
     S = x.shape[0]
     H, Hkv, dh = m["num_heads"], m["num_kv_heads"], m["head_dim"]
     a = p["attn"]
     h = rms_norm(x, p["pre_norm"]["scale"])
-    q, k, v = h @ a["wq"], h @ a["wk"], h @ a["wv"]
+    q, k, v = _mm(h, a["wq"], quant), _mm(h, a["wk"], quant), _mm(h, a["wv"], quant)
     if m["qkv_bias"]:
         q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
     q = rope(q.reshape(S, H, dh), m["rope_theta"])
@@ -85,10 +93,11 @@ def block(m: dict, p: dict, x):
     causal = jnp.tril(jnp.ones((S, S), bool))
     s = jnp.where(causal[None], s, -jnp.inf)
     o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v).reshape(S, H * dh)
-    x = x + o @ a["wo"]
+    x = x + _mm(o, a["wo"], quant)
     h = rms_norm(x, p["mlp_norm"]["scale"])
     mp = p["mlp"]
-    return x + (jax.nn.silu(h @ mp["w_gate"]) * (h @ mp["w_up"])) @ mp["w_down"]
+    up = jax.nn.silu(_mm(h, mp["w_gate"], quant)) * _mm(h, mp["w_up"], quant)
+    return x + _mm(up, mp["w_down"], quant)
 
 
 class Reference:
@@ -98,23 +107,24 @@ class Reference:
 
     def __init__(self, m: dict, seed: int, quant: Optional[str] = None):
         self.m, self.seed, self.quant = m, seed, quant
-        root = weights.root_key(seed)
+        # the key is an argument of each program, so that one compile serves every seed
+        self.root = weights.root_key(seed)
         v = m["vocab_size"]
 
-        def tables():
+        def tables(root):
             emb = weights.embed(root, m)
             table = emb["embedding"].astype(jnp.float32)[:v]
             head = table.T if m["tie_embeddings"] else emb["lm_head"].astype(jnp.float32)[:, :v]
             return table, quantize(head, quant), weights.final_norm(root, m)["scale"]
 
         def head_scores(x, rows, toks, head, scale):
-            logits = rms_norm(x[rows], scale) @ head
+            logits = _mm(rms_norm(x[rows], scale), head, quant)
             at = jnp.take_along_axis(logits, toks[:, None], axis=1)[:, 0]
             return logits.max(axis=1), at, logits.argmax(axis=1)
 
         self._tables = jax.jit(tables)
-        self._layer = jax.jit(lambda i: _f32(weights.layer(root, m, i), quant))
-        self._block = jax.jit(jax.vmap(lambda p, x: block(m, p, x), in_axes=(None, 0)))
+        self._layer = jax.jit(lambda root, i: _f32(weights.layer(root, m, i), quant))
+        self._block = jax.jit(jax.vmap(lambda p, x: block(m, p, x, quant), in_axes=(None, 0)))
         self._head = jax.jit(head_scores)
 
     def score(self, seqs: Sequence[np.ndarray], rows: Sequence[np.ndarray],
@@ -126,7 +136,7 @@ class Reference:
         L = -(-max(len(s) for s in seqs) // 128) * 128
         R = -(-max(len(r) for r in rows) // 128) * 128
         with jax.default_matmul_precision("highest"):
-            table, head, scale = self._tables()
+            table, head, scale = self._tables(self.root)
             xs = []
             for g0 in range(0, len(seqs), group):
                 ids = np.zeros((group, L), np.int32)
@@ -135,7 +145,7 @@ class Reference:
                 xs.append(jnp.take(table, jnp.asarray(ids), axis=0))
             del table
             for i in range(self.m["num_layers"]):
-                p = self._layer(i)
+                p = self._layer(self.root, i)
                 xs = [self._block(p, x) for x in xs]
                 del p
             out = []

@@ -116,6 +116,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         jax.profiler.stop_trace()
     after = c.counters()
+    counters = {k: v - before.get(k, 0) for k, v in after.items()}
     stats = devices[0].memory_stats() or {}
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": stats.get("peak_bytes_in_use")}
@@ -141,12 +142,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                         decoded.append((r.spec.model, r.spec.prompt_len + k - 1))
         o = obs_mod.Observed(
             trace=tdata, window=win,
-            counters={k: after[k] - before[k] for k in after},
+            counters=counters,
             compiles=sum(1 for t in clock.times if t0 <= t <= t1),
             decode_calls=[(m, a) for t, m, a in c.decode_calls if t0 <= t <= t1],
             prefills=prefills, decoded=decoded,
             models={n: conf["model"] for n, conf in c.confs.items()},
-            peaks=peaks, chips=cell["chips"])
+            costs={n: p.costs for n, p in c.parts.items()},
+            peaks=peaks, chips=cell["chips"], served=s)
         for m in names:
             v = obs_mod.read(m["name"], o)
             if v is not None:
@@ -177,7 +179,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             # transients of set-up's and the window's prefill and decode programs
             "bytes_in_use_at_window_start": at_start.get("bytes_in_use"),
             "peak_bytes_at_window_start": at_start.get("peak_bytes_in_use"),
-            "counters": {k: after[k] - before[k] for k in after}}
+            "counters": counters}
     log(f"window: {json.dumps(info)}")
 
     confs = c.confs

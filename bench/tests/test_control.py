@@ -1,8 +1,7 @@
-"""The control of ``correct`` at a test size: the reference with its weights
-rounded to fp8, put in the program's place, comes out not correct through the
-same ``check.verdict`` that passes the program's own tokens
-(``bench/control.py`` makes the same readings on the chip at the cells' own
-sizes)."""
+"""The control of ``correct`` at a test size: the reference computed in fp8,
+put in the program's place, comes out not correct through the same
+``check.verdict`` that passes the program's own tokens (``bench/control.py``
+makes the same readings on the chip at the cells' own sizes)."""
 from pathlib import Path
 
 

@@ -1,10 +1,10 @@
-"""flops_bytes.py against the arithmetic by hand for both configurations."""
+"""costs/dense_gqa.py against the arithmetic by hand for both configurations."""
 import json
 from pathlib import Path
 
 import pytest
 
-from bench import flops_bytes as fb
+from bench.costs import dense_gqa as fb
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -37,8 +37,8 @@ def test_kv_bytes_per_token_per_layer(name, kib):
 
 def test_decode_step_counts_weights_once_and_kv_per_slot():
     m = model("granite-3-8b.pp4")
-    f1, b1 = fb.decode_step(m, [99])
-    f2, b2 = fb.decode_step(m, [99, 199])
+    f1, b1 = fb.decode_step(m, [99], {})
+    f2, b2 = fb.decode_step(m, [99, 199], {})
     assert b1 == fb.decode_weight_bytes(m) + 100 * fb.kv_bytes_per_token(m)
     assert b2 - b1 == 200 * fb.kv_bytes_per_token(m)
     # a token is 2 FLOPs per matmul weight plus 4 * q_dim per key per layer
@@ -50,5 +50,5 @@ def test_decode_step_counts_weights_once_and_kv_per_slot():
 def test_prefill_closed_form_matches_the_sum_over_positions():
     m = model("qwen2-7b.pp4")
     direct = sum(fb._token_flops(m, c, False) for c in range(1, 301)) + 2 * 3584 * 152064
-    assert fb.prefill(m, [300])[0] == direct
+    assert fb.prefill(m, [300], {})[0] == direct
     assert fb.decode_weight_bytes(m) == fb.weight_bytes(m) - 2 * 152064 * 3584

@@ -11,8 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, weights
+from bench import harness
 from bench.reference import dense_gqa
+from bench.weights import dense_gqa as weights
 
 DATA = Path(__file__).resolve().parent / "data"
 TOL = 2e-4  # float32 logits of order 1 through two layers, summed in other orders
@@ -57,3 +58,14 @@ def test_control_rounds_weights():
     q = dense_gqa.quantize(w, "fp8")
     rel = jnp.abs(q - w) / jnp.max(jnp.abs(w), axis=0)
     assert 0 < float(rel.max()) <= 2.0 ** -4 * 1.01
+
+
+def test_control_rounds_the_activations_that_meet_a_weight():
+    """Under the control a product with a weight takes its left operand in
+    fp8, row by row; without it the product is the plain one."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 256))
+    w = jax.random.normal(jax.random.PRNGKey(2), (256, 64))
+    assert (dense_gqa._mm(x, w, None) == x @ w).all()
+    want = dense_gqa.quantize(x.T, "fp8").T @ w
+    np.testing.assert_array_equal(np.asarray(dense_gqa._mm(x, w, "fp8")), np.asarray(want))
+    assert not np.allclose(np.asarray(want), np.asarray(x @ w), rtol=1e-3)

@@ -54,3 +54,54 @@ def test_from_xplane_reads_host_spans(tmp_path):
     rounds = t["spans"]["bench._serve_round"]
     assert len(rounds) == 3
     assert all(w[0] <= s < e <= w[1] for s, e in rounds)
+
+
+@pytest.fixture(scope="module")
+def traced_cell(tmp_path_factory):
+    """A tiny closed-loop cell built, warmed and served for a second and a
+    half under the profiler, with the benchmark's spans installed as in a
+    traced run; returns (cell, trace, counters before, counters after)."""
+    from bench import harness, spans, traffic
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(harness, "CONFIG_DIR", DATA)
+        mp.setattr(traffic, "MIX_DIR", DATA)
+        mix = traffic.load_mix("tiny-closed")
+        c = harness.Cell(mix, "tiny-granite", 2**32 + 9)
+        c.warm()
+        before = c.counters()
+        spans.install(c.eng)
+        log_dir = str(tmp_path_factory.mktemp("trace"))
+        jax.profiler.start_trace(log_dir)
+        with jax.profiler.TraceAnnotation("bench.window"):
+            c.serve(1.5, traffic.Schedule(mix, 2**32 + 9, "tiny-granite"))
+        jax.profiler.stop_trace()
+        return c, tr.from_xplane(tr.find_xplane(log_dir)), before, c.counters()
+
+
+def test_from_xplane_keeps_program_spans_beside_the_benchmarks(traced_cell):
+    _, t, _, _ = traced_cell
+    w = tr.window_of(t)
+    assert {"bench._serve_round", "bench.decode_pool", "repro.engine.round",
+            "repro.decode.step"} <= set(t["spans"])
+    assert all(n.startswith(("bench.", "repro.")) for n in t["spans"])
+    # the program's spans change no reading of the benchmark's: span_seconds
+    # on the trace reads what it reads on the benchmark's spans alone
+    alone = {"spans": {n: v for n, v in t["spans"].items() if n.startswith("bench.")}}
+    for names in (["_serve_round"], ["_admit"], ["_plan_for", "_prefill_plan_for", "admission.decide"]):
+        assert tr.span_seconds(t, w, names) == tr.span_seconds(alone, w, names)
+    rounds = tr.span_seconds(t, w, ["_serve_round"])[1]
+    assert rounds > 0
+    assert len([s for s, _ in t["spans"]["repro.engine.round"] if w[0] <= s < w[1]]) == rounds
+    # an idle moment inside a program span is named by its whole name
+    index = tr.SpanIndex(t["spans"])
+    s, e = t["spans"]["repro.decode.step"][-1]
+    assert index.label((s + e) / 2).startswith("repro.")
+
+
+def test_cell_counters_hold_the_programs_ledger(traced_cell):
+    c, _, before, after = traced_cell
+    for key in ("plan_lookups", "admitted", "queue_wait_us"):
+        assert key in after
+        assert after[key] - before.get(key, 0) > 0
+    assert after["prefill_batches"] == c.eng.prefill_batches  # the engine's own are kept

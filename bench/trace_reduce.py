@@ -3,22 +3,25 @@
 ``from_xplane`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into a
 plain dict of intervals (seconds on the trace's clock):
 
-    {"spans":   {name: [[start, end], ...]},      # host annotations "bench.*"
+    {"spans":   {name: [[start, end], ...]},      # host annotations "bench.*", "repro.*"
      "devices": [{"name": plane, "ops": [[name, start, end], ...],
                   "modules": [[name, start, end], ...]}]}
 
-The same dict, written as JSON, is what the tests read. Everything else here
-works on that dict.
+The host spans are the benchmark's (``bench/spans.py``) and the program's own
+(``repro.*``), each under its whole name. The same dict, written as JSON, is
+what the tests read. Everything else here works on that dict.
 """
 from __future__ import annotations
 
 import bisect
 import glob
+import itertools
 import os
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 Interval = Tuple[float, float]
 
 
@@ -52,7 +55,7 @@ def from_xplane(path: str) -> dict:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
                         spans[e.name].append([e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9])
         elif plane.name.startswith("/device:TPU:"):
             devices.append({"name": plane.name, "ops": _events(_line(plane, "XLA Ops")),
@@ -109,17 +112,24 @@ def idle_gaps(device: dict, window: Interval) -> List[Interval]:
 
 
 class SpanIndex:
-    """Host spans sorted by start, to name what the host was doing at a time."""
+    """Host spans sorted by start, to name what the host was doing at a time:
+    the benchmark's by their call (``bench.`` dropped), the program's by
+    their whole name."""
 
     def __init__(self, spans: Dict[str, List[Interval]]):
-        self.items = sorted((s, e, name[len(SPAN_PREFIX):]) for name, ivs in spans.items()
+        self.items = sorted((s, e, name.removeprefix(SPAN_PREFIX)) for name, ivs in spans.items()
                             if name != SPAN_PREFIX + "window" for s, e in ivs)
         self.starts = [s for s, _, _ in self.items]
+        # the latest end among the spans that start at or before each one:
+        # once it is at or before t, no earlier span is running at t
+        self.reach = list(itertools.accumulate((e for _, e, _ in self.items), max))
 
     def label(self, t: float) -> str:
         """The innermost span running at ``t`` (of those running, the one
         that began last); "no span" where the host was in none."""
         for i in range(bisect.bisect_right(self.starts, t) - 1, -1, -1):
+            if self.reach[i] <= t:
+                break
             if self.items[i][1] > t:
                 return self.items[i][2]
         return "no span"

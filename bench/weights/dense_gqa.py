@@ -76,10 +76,11 @@ def final_norm(root, m: dict):
 def served_params(seed: int, m: dict):
     """The whole model in the program's layout, made on the device in one
     jitted call: one scanned stage of ``num_layers`` stacked layers, whose
-    single layer of the pattern is ``l0``."""
-    def make():
-        root = root_key(seed)
+    single layer of the pattern is ``l0``. The root key is the program's
+    argument, not a constant in it, so one compiled program (kept in the
+    persistent cache) makes every seed's weights."""
+    def make(root):
         stack = jax.vmap(lambda i: layer(root, m, i))(jnp.arange(m["num_layers"]))
         return {"embed": embed(root, m), "final_norm": final_norm(root, m),
                 "stages": [{"l0": stack}]}
-    return jax.jit(make)()
+    return jax.jit(make)(root_key(seed))
