@@ -62,6 +62,13 @@ class ModelConfig:
     #   "attn" | "local" | "global" | "mamba" | "ssd"
     layer_pattern: Tuple[str, ...] = ("attn",)
     rope_theta: float = 10_000.0
+    # YaRN rope scaling (DeepSeek-V2's "rope_scaling"); None keeps plain rope
+    yarn_factor: Optional[float] = None
+    yarn_original_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     tie_embeddings: bool = False
     post_block_norm: bool = False  # gemma2-style pre+post norms
@@ -80,9 +87,8 @@ class ModelConfig:
     moe_d_ff: int = 0
     moe_layer_period: int = 1  # MoE every k-th layer; others dense
     moe_layer_offset: int = 0  # which index within the period is MoE
-    # dispatch-buffer capacity factor: C = ceil(T*k*cf/E). 1.25 is the
-    # production (dropping) setting; cf >= E/k is provably drop-free.
-    moe_capacity_factor: float = 1.25
+    # renormalise the top-k gates to sum to 1 (DeepSeek-V2's norm_topk_prob)
+    norm_topk_prob: bool = True
     first_dense_layers: int = 0
     router_aux_loss: float = 0.01
 
@@ -264,8 +270,6 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         changes["num_shared_experts"] = min(cfg.num_shared_experts, 1)
         changes["top_k"] = min(cfg.top_k, 2)
         changes["moe_d_ff"] = 256
-        # drop-free at smoke-test scale so decode == forward exactly
-        changes["moe_capacity_factor"] = changes["num_experts"] / changes["top_k"]
     changes["first_dense_layers"] = min(cfg.first_dense_layers, 1 if cfg.num_layers > 1 else 0)
     if cfg.use_mla:
         changes["kv_lora_rank"] = 64

@@ -6,6 +6,8 @@ NOTE: the assigned line lists both "64e" and "160 routed"; the released
 V2-Lite has 64 routed experts (V2-full has 160). We follow 64 routed +
 2 shared, top-6, expert d_ff=1408, MLA kv_lora_rank=512 (qk_nope=128,
 qk_rope=64, v=128), first layer dense (d_ff=10944, per model card).
+Routing as published (softmax, greedy top-6, ``norm_topk_prob`` false) and
+YaRN rope on the 64 rope dims (``rope_scaling`` of the published config.json).
 """
 from repro.configs.base import ModelConfig
 
@@ -31,4 +33,11 @@ CONFIG = ModelConfig(
     moe_d_ff=1408,
     moe_layer_period=1,
     first_dense_layers=1,
+    norm_topk_prob=False,
+    yarn_factor=40.0,
+    yarn_original_positions=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
 )

@@ -17,7 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init, rms_norm_head, split
+from repro.models.layers import (apply_rope, dense_init, rms_norm_head, rope_scaling, split,
+                                 yarn_mscale)
 
 _FULL_KV_LIMIT = 2048
 _KV_BLOCK = 1024
@@ -289,7 +290,12 @@ def init_mla(rng, cfg):
 
 
 def _mla_scale(cfg):
-    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    """Softmax scale; under YaRN times mscale(factor, mscale_all_dim)^2, as
+    DeepSeek-V2 sets it."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor is not None and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def _mla_compress(p, x, cfg, positions):
@@ -297,7 +303,8 @@ def _mla_compress(p, x, cfg, positions):
     ckr = x @ p["w_dkv"]
     c_kv, k_rope = ckr[..., : cfg.kv_lora_rank], ckr[..., cfg.kv_lora_rank:]
     c_kv = rms_norm_head(c_kv, p["kv_norm"])
-    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta,
+                        rope_scaling(cfg))[..., 0, :]
     return c_kv, k_rope
 
 
@@ -306,7 +313,7 @@ def _mla_queries(p, x, cfg, positions):
     H, nope, rope_d = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     q = (x @ p["wq"]).reshape(B, S, H, nope + rope_d)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, rope_scaling(cfg))
     return q_nope, q_rope
 
 

@@ -5,6 +5,8 @@ Pure-functional JAX: params are nested dicts of jnp arrays; every layer is
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -58,16 +60,52 @@ def rms_norm_head(x, scale, eps=1e-6):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim, theta):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor, mscale):
+    """YaRN's magnitude correction for a context stretched ``factor`` times."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., S, H, Dh) ; positions: (..., S) int32."""
+def rope_scaling(cfg):
+    """The config's YaRN scaling as a hashable tuple (factor, original
+    positions, beta_fast, beta_slow, cos/sin scale), or None for plain rope."""
+    if cfg.yarn_factor is None:
+        return None
+    f = cfg.yarn_factor
+    return (f, cfg.yarn_original_positions, cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+            yarn_mscale(f, cfg.yarn_mscale) / yarn_mscale(f, cfg.yarn_mscale_all_dim))
+
+
+def yarn_ramp(head_dim, theta, original, beta_fast, beta_slow):
+    """(low, high) frequency indices of YaRN's linear ramp: indices at or
+    below ``low`` keep their frequency, those at or above ``high`` are
+    interpolated, those between blend linearly."""
+    def dim(rotations):
+        return head_dim * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), head_dim - 1)
+    return low, (high if high > low else low + 0.001)
+
+
+def rope_freqs(head_dim, theta, scaling=None):
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    factor, original, beta_fast, beta_slow, _ = scaling
+    low, high = yarn_ramp(head_dim, theta, original, beta_fast, beta_slow)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta, scaling=None):
+    """x: (..., S, H, Dh) ; positions: (..., S) int32. ``scaling`` is
+    :func:`rope_scaling`'s tuple (YaRN) or None."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)  # (Dh/2,)
+    freqs = rope_freqs(dh, theta, scaling)  # (Dh/2,)
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,Dh/2)
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if scaling is not None and scaling[4] != 1.0:
+        sin, cos = sin * scaling[4], cos * scaling[4]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -9,6 +9,7 @@ families through one interface:
   cache                   = init_cache(cfg, B, max_len, enc_len=...)
   logits, cache           = prefill(params, cfg, inputs, cache, ctx, ...)
   logits, cache           = decode_step(params, cfg, token, cache, pos, ctx)
+  logits, cache, counts   = decode_step(..., route_mask=mask)   # expert layers' routing
 
 Inputs: token ids (B,S) int32 for ``input_mode=tokens``; for the audio
 frontend stub, ``enc_inputs`` are precomputed frame embeddings (B,T,d_model).
@@ -41,8 +42,8 @@ def init_params(rng, cfg):
 def encode(params, cfg, enc_inputs, ctx):
     """Audio/enc-dec: enc_inputs (B, T_frames, d_model) frame embeddings."""
     x = enc_inputs.astype(jnp.dtype(cfg.dtype))
-    x, _, _ = tfm.apply_stack(params["encoder"]["stages"], cfg, x, ctx,
-                              mode="encode", cross=True)
+    x, _, _, _ = tfm.apply_stack(params["encoder"]["stages"], cfg, x, ctx,
+                                 mode="encode", cross=True)
     return apply_norm(params["encoder"]["final_norm"], x, cfg)
 
 
@@ -58,7 +59,7 @@ def train_logits(params, cfg, batch, ctx=ExecContext()):
     if cfg.is_encoder_decoder:
         enc_out = encode(params, cfg, batch["enc_inputs"], ctx)
     x = _embed_inputs(params, cfg, batch["tokens"])
-    x, aux, _ = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="train", enc_out=enc_out)
+    x, aux, _, _ = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="train", enc_out=enc_out)
     x = apply_norm(params["final_norm"], x, cfg)
     return lm_logits(params["embed"], x, cfg), aux
 
@@ -116,22 +117,29 @@ def prefill(params, cfg, inputs, cache, ctx=ExecContext(), enc_inputs=None,
     if cfg.is_encoder_decoder:
         enc_out = encode(params, cfg, enc_inputs, ctx)
     x = _embed_inputs(params, cfg, inputs)
-    x, _, cache = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="prefill",
-                                  cache=cache, enc_out=enc_out,
-                                  ssm_mask=pad_mask)
+    x, _, cache, _ = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="prefill",
+                                     cache=cache, enc_out=enc_out,
+                                     ssm_mask=pad_mask)
     x = apply_norm(params["final_norm"], x, cfg)
     return lm_logits(params["embed"], x, cfg), cache
 
 
-def decode_step(params, cfg, token, cache, pos, ctx=ExecContext(), enc_len=None):
+def decode_step(params, cfg, token, cache, pos, ctx=ExecContext(), enc_len=None,
+                route_mask=None):
     """token (B,1) int32; pos scalar int32 (position-synchronous batch) or
     (B,) int32 per-sequence write positions (ragged continuous batching).
     ``enc_len`` (enc-dec only): scalar or (B,) valid encoder-cache lengths —
     a slot pool preallocates the cross-attention region at ``max_enc_len``,
     so decode must mask each row's cross-attention to its own encoder
-    length. ``None`` keeps the exact-length (unmasked) reference semantics."""
+    length. ``None`` keeps the exact-length (unmasked) reference semantics.
+    ``route_mask`` (B,) bool (single-token steps): also return the expert
+    layers' per-expert assignment counts of the masked rows,
+    (logits, cache, counts) with counts (expert layers, E) int32."""
     x = embed_tokens(params["embed"], token, cfg).astype(jnp.dtype(cfg.dtype))
-    x, _, cache = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="decode",
-                                  cache=cache, pos=pos, enc_len=enc_len)
+    x, _, cache, counts = tfm.apply_stack(params["stages"], cfg, x, ctx, mode="decode",
+                                          cache=cache, pos=pos, enc_len=enc_len,
+                                          route_mask=route_mask)
     x = apply_norm(params["final_norm"], x, cfg)
+    if route_mask is not None:
+        return lm_logits(params["embed"], x, cfg), cache, counts
     return lm_logits(params["embed"], x, cfg), cache

@@ -1,16 +1,16 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts with expert parallelism, without dropped tokens.
 
-Baseline distribution scheme (paper-faithful "partition by processor class"
+Distribution scheme (paper-faithful "partition by processor class"
 analogue): experts are sharded across the ``model`` mesh axis; every model
 shard routes the full local token set, computes ONLY its local experts'
-contributions via a capacity-bounded dispatch buffer, and the contributions
-are combined with a single ``psum`` over the model axis (one all-reduce of
-activations). The optimized all-to-all dispatch variant lives in
-``moe_a2a.py`` (§Perf hillclimb).
+contributions, and the contributions are combined with a single ``psum``
+over the model axis (one all-reduce of activations).
 
-The dispatch uses the sort-free "argsort + searchsorted" position trick —
-no (T, E) one-hot is ever materialised, so it scales to 384 experts x 1M
-tokens.
+Dispatch has no capacity: the T*k (token, expert) assignments are sorted by
+expert and the three expert matmuls run as grouped matmuls
+(``jax.lax.ragged_dot``) over the sorted rows, so every assignment is
+computed, however unevenly the router spreads them, and the FLOPs follow the
+assignments. No (T, E) one-hot and no per-expert buffer is materialised.
 """
 from __future__ import annotations
 
@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init, split
-
-CAPACITY_FACTOR = 1.25
 
 
 def init_moe(rng, cfg):
@@ -44,51 +42,48 @@ def init_moe(rng, cfg):
     return p
 
 
-def _capacity(T, k, E, cf=CAPACITY_FACTOR):
-    """Static per-local-expert capacity given T local tokens."""
-    per = T * k * cf / E
-    return max(1, int(-(-per // 1)))
-
-
-def _local_expert_partial(xt, gates, ids, wg, wu, wd, e0, E_l, C):
+def _local_expert_partial(xt, gates, ids, wg, wu, wd, e0):
     """Contribution of experts [e0, e0+E_l) to all T local tokens.
 
     xt (T,D); gates/ids (T,k); wg/wu (E_l,D,F); wd (E_l,F,D).
-    Returns out (T,D) float32 partial sum.
+    Returns out (T,D) float32 partial sum. Assignments to experts this shard
+    does not hold sort after the local ones, past the last group, where the
+    grouped matmuls compute nothing for them.
     """
     T, D = xt.shape
     k = ids.shape[1]
-    flat_e = ids.reshape(-1)
-    flat_g = gates.reshape(-1)
-    tok = jnp.arange(T * k) // k
-    local = (flat_e >= e0) & (flat_e < e0 + E_l)
-    le = jnp.where(local, flat_e - e0, E_l)  # E_l == drop bucket
+    E_l = wg.shape[0]
+    le = ids.reshape(-1) - e0
+    local = (le >= 0) & (le < E_l)
+    le = jnp.where(local, le, E_l)
     order = jnp.argsort(le, stable=True)
-    se = le[order]
-    stok = tok[order]
-    sg = flat_g[order]
-    starts = jnp.searchsorted(se, jnp.arange(E_l))
-    pos = jnp.arange(T * k) - starts[jnp.minimum(se, E_l - 1)]
-    valid = (se < E_l) & (pos < C)
-    slot = jnp.where(valid, se * C + jnp.where(valid, pos, 0), E_l * C)
-    # dispatch: scatter tokens into (E_l*C [+1 drop], D)
-    buf = jnp.zeros((E_l * C + 1, D), xt.dtype).at[slot].add(xt[stok])
-    buf = buf[: E_l * C].reshape(E_l, C, D)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, wg)) * jnp.einsum("ecd,edf->ecf", buf, wu)
-    yb = jnp.einsum("ecf,efd->ecd", h, wd).reshape(E_l * C, D)
-    # combine: gather each assignment's slot output, weight by gate
-    contrib = jnp.where(valid[:, None], yb[jnp.minimum(slot, E_l * C - 1)], 0.0)
-    contrib = contrib.astype(jnp.float32) * sg[:, None]
-    out = jnp.zeros((T, D), jnp.float32).at[stok].add(contrib)
-    return out
+    sizes = jnp.zeros((E_l,), jnp.int32).at[le].add(1, mode="drop")
+    rows = xt[order // k]  # assignment order -> its token's row
+    h = (jax.nn.silu(jax.lax.ragged_dot(rows, wg, sizes))
+         * jax.lax.ragged_dot(rows, wu, sizes))
+    y = jax.lax.ragged_dot(h, wd, sizes)  # (T*k, D), sorted by expert
+    # back to assignment order; rows of other shards' experts are not computed
+    y = y[jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))]
+    w = jnp.where(local, gates.reshape(-1), 0.0)
+    y = jnp.where(local[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
+    return y.reshape(T, k, D).sum(axis=1)
 
 
-def _route(xt, router_w, k):
-    logits = xt.astype(jnp.float32) @ router_w  # (T,E)
+def _route(xt, router_w, k, renormalize=True):
+    """Softmax router in float32 (its matmul at full precision), greedy top-k;
+    ``renormalize`` scales the k gates to sum to 1."""
+    logits = jnp.dot(xt.astype(jnp.float32), router_w,
+                     precision=jax.lax.Precision.HIGHEST)  # (T,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if renormalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return probs, gates, ids
+
+
+def _route_counts(ids, mask, E):
+    """(E,) int32: assignments per expert of the tokens where ``mask``."""
+    return jnp.zeros((E,), jnp.int32).at[ids].add(mask[:, None].astype(jnp.int32))
 
 
 def _aux_loss(probs, ids, E):
@@ -112,13 +107,12 @@ def _moe_2d(p, x, cfg, ctx):
     E_l = E // M
     ax = ctx.model_axis
     daxes = tuple(ctx.batch_axes)
-    C = _capacity(B * S, k, E, cfg.moe_capacity_factor)
 
     def fn(x_l, rw, wg, wu, wd):
         xt = x_l.reshape(B * S, D)
-        probs, gates, ids = _route(xt, rw, k)
+        probs, gates, ids = _route(xt, rw, k, cfg.norm_topk_prob)
         m = jax.lax.axis_index(ax)
-        out = _local_expert_partial(xt, gates, ids, wg, wu, wd, m * E_l, E_l, C)
+        out = _local_expert_partial(xt, gates, ids, wg, wu, wd, m * E_l)
         out = jax.lax.psum(out, (ax,) + daxes)
         aux = jax.lax.pmean(_aux_loss(probs, ids, E), ax)
         return out.reshape(B, S, D).astype(x_l.dtype), aux
@@ -132,8 +126,11 @@ def _moe_2d(p, x, cfg, ctx):
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 
-def moe_apply(p, x, cfg, ctx):
-    """x (B,S,D) -> (out (B,S,D), aux_loss scalar f32)."""
+def moe_apply(p, x, cfg, ctx, token_mask=None):
+    """x (B,S,D) -> (out (B,S,D), aux_loss scalar f32, counts). With
+    ``token_mask`` (B*S,) bool, ``counts`` is (E,) int32: the assignments the
+    router made to each expert from the masked tokens (a decode step's active
+    slots); else None."""
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.top_k
     M = ctx.model_parallel
@@ -146,16 +143,14 @@ def moe_apply(p, x, cfg, ctx):
         mesh = ctx.mesh
         bspec = P(ctx.batch_axes if B % max(1, ctx.batch_parallel) == 0 and ctx.batch_parallel > 1 else None,
                   None, None)
-        T_local = (B // max(1, ctx.batch_parallel) if bspec[0] is not None else B) * S
-        C = _capacity(T_local, k, E, cfg.moe_capacity_factor)
         ax = ctx.model_axis
 
         def fn(x_l, rw, wg, wu, wd):
             Bl, Sl, _ = x_l.shape
             xt = x_l.reshape(Bl * Sl, D)
-            probs, gates, ids = _route(xt, rw, k)
+            probs, gates, ids = _route(xt, rw, k, cfg.norm_topk_prob)
             m = jax.lax.axis_index(ax)
-            out = _local_expert_partial(xt, gates, ids, wg, wu, wd, m * E_l, E_l, C)
+            out = _local_expert_partial(xt, gates, ids, wg, wu, wd, m * E_l)
             out = jax.lax.psum(out, ax)
             aux = jax.lax.pmean(_aux_loss(probs, ids, E), ax)
             return out.reshape(Bl, Sl, D).astype(x_l.dtype), aux
@@ -168,14 +163,19 @@ def moe_apply(p, x, cfg, ctx):
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     else:
         xt = x.reshape(B * S, D)
-        probs, gates, ids = _route(xt, p["router"], k)
-        C = _capacity(B * S, k, E, cfg.moe_capacity_factor)
-        out = _local_expert_partial(xt, gates, ids, p["w_gate"], p["w_up"], p["w_down"], 0, E, C)
+        probs, gates, ids = _route(xt, p["router"], k, cfg.norm_topk_prob)
+        out = _local_expert_partial(xt, gates, ids, p["w_gate"], p["w_up"], p["w_down"], 0)
         aux = _aux_loss(probs, ids, E)
         out = out.reshape(B, S, D).astype(x.dtype)
+
+    counts = None
+    if token_mask is not None:
+        if M > 1 and E % M == 0:  # the sharded paths route inside their shard_map
+            _, _, ids = _route(x.reshape(B * S, D), p["router"], k, cfg.norm_topk_prob)
+        counts = _route_counts(ids, token_mask, E)
 
     if cfg.num_shared_experts:
         sp = p["shared"]
         h = jax.nn.silu(x @ sp["w_gate"]) * (x @ sp["w_up"])
         out = out + h @ sp["w_down"]
-    return out, aux
+    return out, aux, counts

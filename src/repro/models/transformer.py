@@ -111,10 +111,14 @@ def _window(cfg, kind):
 
 
 def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
-                enc_out=None, causal=True, enc_len=None, ssm_mask=None):
-    """Returns (x, aux, new_cache). ``ssm_mask`` (B, S) marks valid prompt
-    positions for pad-bucketed SSM prefill; attention mixers reject it
-    (their positions are absolute, so left padding would shift rope)."""
+                enc_out=None, causal=True, enc_len=None, ssm_mask=None,
+                route_mask=None):
+    """Returns (x, aux, new_cache, counts). ``ssm_mask`` (B, S) marks valid
+    prompt positions for pad-bucketed SSM prefill; attention mixers reject it
+    (their positions are absolute, so left padding would shift rope).
+    ``route_mask`` (B*S,) bool asks an expert layer for its per-expert
+    assignment counts of the masked tokens (``counts``, (E,) int32); other
+    layers, or no mask, give None."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = dict(cache) if cache is not None else None
     h = apply_norm(lp["pre_norm"], x, cfg)
@@ -206,16 +210,17 @@ def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
         x = x + xo
 
     # ---- mlp ----
+    counts = None
     if mlp_kind != "none":
         h2 = apply_norm(lp["mlp_norm"], x, cfg)
         if mlp_kind == "moe":
-            y, aux = moe_mod.moe_apply(lp["mlp"], h2, cfg, ctx)
+            y, aux, counts = moe_mod.moe_apply(lp["mlp"], h2, cfg, ctx, token_mask=route_mask)
         else:
             y = apply_mlp(lp["mlp"], h2, cfg)
         if cfg.post_block_norm:
             y = apply_norm(lp["mlp_post_norm"], y, cfg)
         x = x + y
-    return x, aux, new_cache
+    return x, aux, new_cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -254,10 +259,14 @@ def init_stack_cache(cfg, batch, max_len, dtype, decoder_cross=False, enc_len=0)
 
 
 def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
-                enc_out=None, cross=False, enc_len=None, ssm_mask=None):
+                enc_out=None, cross=False, enc_len=None, ssm_mask=None,
+                route_mask=None):
+    """Returns (x, aux, caches, counts): ``counts`` is (expert layers, E)
+    int32, each expert layer's assignments from the tokens ``route_mask``
+    marks, where a mask is given and the stack has expert layers; else None."""
     stages = compute_stages(cfg, cross=cross)
     aux_total = jnp.zeros((), jnp.float32)
-    new_caches = []
+    new_caches, counts = [], []
     for si, st in enumerate(stages):
         sp = stage_params[si]
         sc = cache[si] if cache is not None else None
@@ -265,16 +274,18 @@ def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
         def body(carry, xs, _pattern=st.pattern):
             xc, aux = carry
             lp, cin = xs if sc is not None else (xs, None)
-            cout = {}
+            cout, cnt = {}, []
             for j, (kind, mlp) in enumerate(_pattern):
-                xc, a, cj = apply_layer(
+                xc, a, cj, nj = apply_layer(
                     lp[f"l{j}"], xc, cfg, kind, mlp, ctx, mode,
                     cin[f"l{j}"] if cin is not None else None, pos,
                     enc_out=enc_out, causal=not cross, enc_len=enc_len,
-                    ssm_mask=ssm_mask)
+                    ssm_mask=ssm_mask, route_mask=route_mask)
                 aux = aux + a
                 cout[f"l{j}"] = cj
-            return (xc, aux), (cout if sc is not None else None)
+                if nj is not None:
+                    cnt.append(nj)
+            return (xc, aux), (cout if sc is not None else None, cnt)
 
         if mode == "train":
             # remat policy is a partitioner/plan knob (§Perf): "full" remats
@@ -313,6 +324,8 @@ def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
             new_caches.append(None)
             continue
         xs = (sp, sc) if sc is not None else sp
-        (x, aux_total), c_new = jax.lax.scan(fn, (x, aux_total), xs)
+        (x, aux_total), (c_new, cnt) = jax.lax.scan(fn, (x, aux_total), xs)
         new_caches.append(c_new)
-    return x, aux_total, (new_caches if cache is not None else None)
+        counts += cnt  # each (repeats, E)
+    return (x, aux_total, (new_caches if cache is not None else None),
+            jnp.concatenate(counts) if counts else None)

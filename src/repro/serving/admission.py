@@ -21,6 +21,12 @@ from repro.serving.scheduler import AdaOperScheduler
 from repro.serving.slots import Request, Response, _ActiveSeq, _SlotPool
 from repro.serving.workers import ModelWorker
 
+# the most prompt tokens (rows x length) one prefill call takes: a larger
+# same-shape group is prefilled in pow2 sub-batches, which bounds a prefill's
+# activations (expanded attention scores, an expert layer's T*k rows)
+# whatever the pool's size
+PREFILL_TOKENS = 32768
+
 
 class AdmissionPolicy:
     """Energy-aware iteration-level admission (the AdaOper objective applied
@@ -223,7 +229,14 @@ def prefill_group(eng, model: str, pool: _SlotPool,
     (padding rows are dropped), and the admission plan is charged once
     per bucket — per-request energy normalised by the plan's bucketed
     batch, the virtual clock advanced by one bucket latency, one
-    ``prefill`` StepEvent appended to the ledger."""
+    ``prefill`` StepEvent appended to the ledger. A group of more than
+    ``PREFILL_TOKENS`` prompt tokens goes in sub-batches."""
+    longest = max(len(s.req.prompt) for s in group)
+    rows = 1 << (max(1, PREFILL_TOKENS // longest).bit_length() - 1)
+    if len(group) > rows:
+        for i in range(0, len(group), rows):
+            prefill_group(eng, model, pool, group[i:i + rows], out, temperature)
+        return
     with span("repro.prefill.group"):
         w = eng.workers[model]
         G = len(group)

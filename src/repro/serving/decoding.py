@@ -40,7 +40,8 @@ def plain_step(eng, model: str, pool: _SlotPool, out: List[Response],
         w = eng.workers[model]
         enc_len = pool.enc_len if w.cfg.is_encoder_decoder else None
         next_tok, logits, pool.cache = w.decode_pool(pool.cache, pool.tokens,
-                                                     pool.pos, enc_len=enc_len)
+                                                     pool.pos, enc_len=enc_len,
+                                                     active=pool.active)
         n_active = len(pool.active)
         step_energy = 0.0
         if eng.scheduler is not None:

@@ -147,7 +147,7 @@ class ServingEngine:
         optional ``SpecConfig``); the default ``draft=None`` keeps every
         decode bit-identical to the pre-speculation engine."""
         self.workers[name] = ModelWorker(name, cfg, params, max_len, ctx,
-                                         max_enc_len=max_enc_len)
+                                         max_enc_len=max_enc_len, ledger=self.ledger)
         self.queues[name] = []
         self.stats[name] = []
         self.priorities[name] = priority

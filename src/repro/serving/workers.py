@@ -29,12 +29,16 @@ from repro.sharding.context import ExecContext
 class ModelWorker:
     def __init__(self, name: str, cfg, params, max_len: int = 512,
                  ctx: ExecContext = ExecContext(),
-                 max_enc_len: Optional[int] = None):
+                 max_enc_len: Optional[int] = None, ledger=None):
         self.name = name
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
         self.ctx = ctx
+        # an expert model's decode steps count their routing into the
+        # engine's ledger, under "<name>.moe_decode_*"
+        self.ledger = ledger
+        self._count_routes = bool(cfg.num_experts) and ledger is not None
         # enc-dec slot pools preallocate the cross-attention cache region at
         # this length; decoder-only models carry no encoder region
         self.max_enc_len = (max_enc_len if max_enc_len is not None
@@ -93,10 +97,15 @@ class ModelWorker:
                                           pad_mask=pad_mask)
         return logits[:, -1], cache
 
-    def _decode_impl(self, params, cache, token, pos, enc_len=None):
-        logits, cache = model_lib.decode_step(params, self.cfg, token, cache,
-                                              pos, self.ctx, enc_len=enc_len)
-        return logits[:, -1], cache
+    def _decode_impl(self, params, cache, token, pos, enc_len=None, active=None):
+        if active is None:
+            logits, cache = model_lib.decode_step(params, self.cfg, token, cache,
+                                                  pos, self.ctx, enc_len=enc_len)
+            return logits[:, -1], cache
+        logits, cache, counts = model_lib.decode_step(
+            params, self.cfg, token, cache, pos, self.ctx, enc_len=enc_len,
+            route_mask=active)
+        return logits[:, -1], cache, counts
 
     def _verify_impl(self, params, cache, tokens, pos):
         # multi-position decode (speculative verify / draft catch-up): keep
@@ -206,7 +215,7 @@ class ModelWorker:
                                 jnp.asarray(slots, dtype=jnp.int32))
 
     def decode_pool(self, pool_cache, tokens: np.ndarray, pos: np.ndarray,
-                    enc_len=None):
+                    enc_len=None, active=None):
         """One ragged decode step over the whole slot pool. ``tokens``
         (max_slots,1) int32, ``pos`` (max_slots,) int32 per-slot write
         positions, ``enc_len`` (max_slots,) per-slot encoder lengths for
@@ -214,13 +223,31 @@ class ModelWorker:
         region). Reuses the jitted decode body — a (B,) position vector
         traces the ragged path in the model. Returns (greedy next tokens
         (max_slots,) np.int32, logits (max_slots, V) for per-slot sampling,
-        cache)."""
-        logits, pool_cache = self._decode(
-            self.params, pool_cache, jnp.asarray(tokens),
-            jnp.asarray(pos, dtype=jnp.int32),
-            None if enc_len is None else jnp.asarray(enc_len, dtype=jnp.int32))
-        with span("repro.decode.wait"):  # the host waits for the step here
-            next_tok = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+        cache).
+
+        An expert model counts, per step, what its router did for the slots
+        in ``active`` (every slot if None): the step's expert layers
+        (``moe_decode_layer_steps``), the experts that got a token in each
+        (``moe_decode_experts_touched``) and the busiest one's tokens
+        (``moe_decode_load_max``), summed over the layers. The counts come
+        back with the tokens, in the same host sync."""
+        args = (self.params, pool_cache, jnp.asarray(tokens),
+                jnp.asarray(pos, dtype=jnp.int32),
+                None if enc_len is None else jnp.asarray(enc_len, dtype=jnp.int32))
+        if not self._count_routes:
+            logits, pool_cache = self._decode(*args)
+            with span("repro.decode.wait"):  # the host waits for the step here
+                next_tok = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+            return next_tok, logits, pool_cache
+        mask = np.zeros(len(pos), bool)
+        mask[list(active) if active is not None else slice(None)] = True
+        logits, pool_cache, counts = self._decode(*args, jnp.asarray(mask))
+        with span("repro.decode.wait"):
+            next_tok, counts = jax.device_get(
+                (jnp.argmax(logits, -1).astype(jnp.int32), counts))
+        self.ledger.count(f"{self.name}.moe_decode_layer_steps", counts.shape[0])
+        self.ledger.count(f"{self.name}.moe_decode_experts_touched", int((counts > 0).sum()))
+        self.ledger.count(f"{self.name}.moe_decode_load_max", int(counts.max(axis=1).sum()))
         return next_tok, logits, pool_cache
 
     def decode_verify(self, pool_cache, tokens: np.ndarray, pos: np.ndarray):

@@ -192,6 +192,31 @@ def test_batched_admission_token_identical_to_serial(tiny, temperature):
     assert eng_b.prefill_batch_requests == len(MIXED)
 
 
+def test_prefill_token_cap_splits_a_group(tiny, monkeypatch):
+    """A same-length group of more than ``PREFILL_TOKENS`` prompt tokens is
+    prefilled in pow2 sub-batches of at most that many, serving the same
+    tokens: with a cap of 24, MIXED's pair of 12-token prompts stays one
+    batch and its pairs of 16 and 20 tokens go one request at a time."""
+    from repro.serving import admission
+
+    cfg, params = tiny
+
+    def serve():
+        eng = ServingEngine(mode="continuous", max_slots=8)
+        eng.add_model("m", cfg, params, max_len=48)
+        for r in _mixed_requests(cfg, seed=13):
+            eng.submit("m", r)
+        return {r.uid: r.tokens for r in eng.run_all()}, eng
+
+    whole, eng_w = serve()
+    monkeypatch.setattr(admission, "PREFILL_TOKENS", 24)
+    split, eng_s = serve()
+    for uid in whole:
+        np.testing.assert_array_equal(split[uid], whole[uid])
+    assert (eng_w.prefill_batches, eng_s.prefill_batches) == (3, 5)
+    assert eng_s.prefill_batch_requests == len(MIXED)
+
+
 # ---------------------------------------------------------------------------
 # encoder-decoder slot caches (continuous path, no bucketed fallback)
 # ---------------------------------------------------------------------------

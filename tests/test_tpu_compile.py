@@ -137,3 +137,21 @@ def test_tinyllama_prefill_compiles_for_v5e(tinyllama_worker, one_chip):
         w.params, _cache(w, one_chip, 1),
         _on(one_chip, (1, POOL_LEN), jnp.int32)).compile()
     _fits_one_chip(compiled)
+
+
+def test_deepseek_stage_decode_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's first pipeline stage (the dense layer and 6 expert
+    layers of 64 experts) decoding a pool of 64 slots x 1536 positions, with
+    its routing counts: it fits one chip, and the routed experts run as
+    grouped matmuls (``ragged-dot``), not as a dense expansion over experts."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), num_layers=7)
+    params = jax.eval_shape(functools.partial(init_params, cfg=cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype), params)
+    w = ModelWorker("deepseek-v2-lite.pp4", cfg, params, max_len=1536)
+    compiled = w._decode.lower(
+        w.params, _cache(w, one_chip, 64), _on(one_chip, (64, 1), jnp.int32),
+        _on(one_chip, (64,), jnp.int32), None, _on(one_chip, (64,), jnp.bool_)).compile()
+    _fits_one_chip(compiled)
+    assert compiled.as_text().count("ragged-dot-none") >= 3 * 6
