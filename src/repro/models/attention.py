@@ -209,48 +209,56 @@ def gqa_forward(p, x, cfg, *, window=None, impl="xla", ctx=None):
     return o.reshape(B, S, cfg.q_dim) @ p["wo"], (k, v)
 
 
-def gqa_decode(p, x, cfg, cache_k, cache_v, pos, *, window=None, impl="xla"):
-    """One-token decode. x (B,1,D); cache_k/v (B,Smax,Hkv,Dh).
+def _decode_positions(pos, B, T):
+    """(B, T) positions of a decode step's tokens: ``pos`` is a scalar (every
+    row at one depth, the bucketed path) or (B,) per-row depths (the ragged
+    slot pool), and row b's T tokens sit at pos[b] .. pos[b] + T - 1."""
+    return jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
+
+
+def _decode_mask(pos, T):
+    """``attend``'s masking for a decode step: one new token sees the cache
+    up to its own position; T > 1 new tokens (the speculative verify) see it
+    causally, among themselves too."""
+    if T > 1:
+        return dict(causal=True, q_offset=pos, kv_len=None)
+    return dict(causal=False, q_offset=pos, kv_len=pos + 1)
+
+
+def _write_rows(stack, layer, positions, rows):
+    """Scatter a decode step's new rows (B, T, ...) into a stage's stacked
+    cache (layers, B, Smax, ...) at [layer, b, positions[b, t]], in place:
+    nothing else of the stack is written. Out-of-range positions drop."""
+    bidx = jnp.arange(rows.shape[0])[:, None]
+    return stack.at[layer, bidx, positions].set(rows.astype(stack.dtype), mode="drop")
+
+
+def gqa_decode(p, x, cfg, stack_k, stack_v, layer, pos, *, window=None, impl="xla"):
+    """Decode step of GQA layer ``layer`` of a stage. x (B,T,D); stack_k/v
+    (layers,B,Smax,Hkv,Dh), the stage's stacked cache, which comes back with
+    this step's K/V rows scattered in and nothing else written.
 
     ``pos`` is either a scalar (position-synchronous batch, the bucketed
     serving path) or a (B,) vector of per-sequence write positions (the
     ragged continuous-batching path, where every cache slot sits at its own
-    depth). The vector path scatters each row's K/V at its own position and
-    masks attention per row with kv_len = pos+1.
+    depth); each row attends up to its own position.
 
-    Speculative verify (vector ``pos`` with T = x.shape[1] > 1): each row
-    scores T candidate positions pos..pos+T-1 in one forward — K/V scatter
-    at the (B,T) position grid (out-of-range writes drop), causal masking
-    among the new queries. Stale cache entries past a row's committed
-    frontier (rejected draft suffixes from an earlier round) sit at
-    kpos > qpos, so the causal mask hides them until they are overwritten —
-    rollback is free."""
+    Speculative verify (vector ``pos`` with T > 1): each row scores T
+    candidate positions pos..pos+T-1 in one forward — K/V scatter at the
+    (B,T) position grid (out-of-range writes drop), causal masking among
+    the new queries. Stale cache entries past a row's committed frontier
+    (rejected draft suffixes from an earlier round) sit at kpos > qpos, so
+    the causal mask hides them until they are overwritten — rollback is
+    free."""
     B, T = x.shape[0], x.shape[1]
     pos = jnp.asarray(pos)
-    if pos.ndim and T > 1:  # ragged multi-position verify
-        positions = pos[:, None] + jnp.arange(T)  # (B,T)
-        q, k, v = _project_qkv(p, x, cfg, positions)
-        bidx = jnp.arange(B)[:, None]
-        cache_k = cache_k.at[bidx, positions].set(k.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[bidx, positions].set(v.astype(cache_v.dtype), mode="drop")
-        o = attend(q, cache_k, cache_v, causal=True, window=window,
-                   softcap=cfg.attn_softcap, q_offset=pos, kv_len=None, impl=impl)
-        return o.reshape(B, T, cfg.q_dim) @ p["wo"], (cache_k, cache_v)
-    positions = jnp.broadcast_to(pos.reshape(-1, 1), (B, 1))
+    positions = _decode_positions(pos, B, T)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    if pos.ndim:  # ragged: per-slot positions
-        bidx = jnp.arange(B)
-        cache_k = cache_k.at[bidx, pos].set(k[:, 0].astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[bidx, pos].set(v[:, 0].astype(cache_v.dtype), mode="drop")
-        o = attend(q, cache_k, cache_v, causal=False, window=window,
-                   softcap=cfg.attn_softcap, q_offset=pos, kv_len=pos + 1, impl=impl)
-        return o.reshape(B, 1, cfg.q_dim) @ p["wo"], (cache_k, cache_v)
-    idx = pos.reshape(())
-    cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), idx, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), idx, axis=1)
-    o = attend(q, cache_k, cache_v, causal=False, window=window,
-               softcap=cfg.attn_softcap, q_offset=idx, kv_len=idx + 1, impl=impl)
-    return o.reshape(B, 1, cfg.q_dim) @ p["wo"], (cache_k, cache_v)
+    stack_k = _write_rows(stack_k, layer, positions, k)
+    stack_v = _write_rows(stack_v, layer, positions, v)
+    o = attend(q, stack_k[layer], stack_v[layer], window=window,
+               softcap=cfg.attn_softcap, impl=impl, **_decode_mask(pos, T))
+    return o.reshape(B, T, cfg.q_dim) @ p["wo"], (stack_k, stack_v)
 
 
 def gqa_cross(p, x, cfg, enc_k, enc_v, enc_len=None, impl="xla"):
@@ -333,35 +341,21 @@ def mla_forward(p, x, cfg, impl="xla"):
     return o.reshape(B, S, H * vd) @ p["wo"], (c_kv, k_rope)
 
 
-def mla_decode(p, x, cfg, cache_ckv, cache_krope, pos, impl="xla"):
-    """Absorbed decode: scores & values live in the kv_lora latent space.
-    ``pos`` scalar or (B,) per-slot positions (see ``gqa_decode``); a (B,)
-    ``pos`` with T = x.shape[1] > 1 is the speculative multi-position verify
-    — latents scatter at the (B,T) grid and the new queries attend causally
-    (stale rejected-suffix latents are causal-masked until overwritten)."""
+def mla_decode(p, x, cfg, stack_ckv, stack_krope, layer, pos, impl="xla"):
+    """Absorbed decode of MLA layer ``layer`` of a stage: scores & values
+    live in the kv_lora latent space. The stacked latent caches (layers, B,
+    Smax, ...) come back with this step's rows scattered in, as in
+    ``gqa_decode``, whose ``pos`` and speculative-verify conventions hold
+    here too (stale rejected-suffix latents are causal-masked until
+    overwritten)."""
     B, T = x.shape[0], x.shape[1]
     H, nope, vd, lr = cfg.num_heads, cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
     pos = jnp.asarray(pos)
-    if pos.ndim and T > 1:  # ragged multi-position verify
-        positions = pos[:, None] + jnp.arange(T)  # (B,T)
-        causal, kv_len, idx = True, None, pos
-        bidx = jnp.arange(B)[:, None]
-        c_kv, k_rope = _mla_compress(p, x, cfg, positions)
-        cache_ckv = cache_ckv.at[bidx, positions].set(c_kv.astype(cache_ckv.dtype), mode="drop")
-        cache_krope = cache_krope.at[bidx, positions].set(k_rope.astype(cache_krope.dtype), mode="drop")
-    else:
-        causal, positions = False, jnp.broadcast_to(pos.reshape(-1, 1), (B, 1))
-        c_kv, k_rope = _mla_compress(p, x, cfg, positions)
-        if pos.ndim:  # ragged: per-slot positions
-            bidx = jnp.arange(B)
-            cache_ckv = cache_ckv.at[bidx, pos].set(c_kv[:, 0].astype(cache_ckv.dtype), mode="drop")
-            cache_krope = cache_krope.at[bidx, pos].set(k_rope[:, 0].astype(cache_krope.dtype), mode="drop")
-            idx = pos
-        else:
-            idx = pos.reshape(())
-            cache_ckv = jax.lax.dynamic_update_slice_in_dim(cache_ckv, c_kv.astype(cache_ckv.dtype), idx, axis=1)
-            cache_krope = jax.lax.dynamic_update_slice_in_dim(cache_krope, k_rope.astype(cache_krope.dtype), idx, axis=1)
-        kv_len = idx + 1
+    positions = _decode_positions(pos, B, T)
+    c_kv, k_rope = _mla_compress(p, x, cfg, positions)
+    stack_ckv = _write_rows(stack_ckv, layer, positions, c_kv)
+    stack_krope = _write_rows(stack_krope, layer, positions, k_rope)
+    cache_ckv, cache_krope = stack_ckv[layer], stack_krope[layer]
     q_nope, q_rope = _mla_queries(p, x, cfg, positions)
     w_uk = p["w_ukv"][..., :nope]  # (lr, H, nope)
     # absorb: q' = q_nope @ W_uk^T  -> latent-space queries (B,T,H,lr)
@@ -369,8 +363,8 @@ def mla_decode(p, x, cfg, cache_ckv, cache_krope, pos, impl="xla"):
     q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)  # (B,T,H,lr+rope)
     k_eff = jnp.concatenate([cache_ckv, cache_krope], axis=-1)[:, :, None, :]  # 1 kv head
     v_eff = cache_ckv[:, :, None, :]  # (B,Smax,1,lr)
-    o_lat = attend(q_eff, k_eff, v_eff, causal=causal, q_offset=idx, kv_len=kv_len,
-                   scale=_mla_scale(cfg), impl=impl)  # (B,T,H,lr)
+    o_lat = attend(q_eff, k_eff, v_eff, scale=_mla_scale(cfg), impl=impl,
+                   **_decode_mask(pos, T))  # (B,T,H,lr)
     w_uv = p["w_ukv"][..., nope:]  # (lr, H, vd)
     o = jnp.einsum("bqhr,rhd->bqhd", o_lat.astype(jnp.float32), w_uv.astype(jnp.float32)).astype(x.dtype)
-    return o.reshape(B, T, H * vd) @ p["wo"], (cache_ckv, cache_krope)
+    return o.reshape(B, T, H * vd) @ p["wo"], (stack_ckv, stack_krope)

@@ -10,7 +10,9 @@ Modes:
   train   — full causal forward, no cache, remat per stage step.
   prefill — full causal forward writing mixer states / KV into a
             preallocated cache at positions [0, S).
-  decode  — one token at position ``pos`` against the cache.
+  decode  — new tokens at ``pos`` against the cache: each stage's stacked
+            cache is carried through the layer loop and gets only the new
+            rows written into it.
 """
 from __future__ import annotations
 
@@ -110,10 +112,14 @@ def _window(cfg, kind):
     return cfg.sliding_window if kind == "local" else None
 
 
-def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
+def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos, layer=None,
                 enc_out=None, causal=True, enc_len=None, ssm_mask=None,
                 route_mask=None):
-    """Returns (x, aux, new_cache, counts). ``ssm_mask`` (B, S) marks valid
+    """Returns (x, aux, new_cache, counts). In decode ``cache`` holds the
+    stage's stacked leaves (layers, B, ...) and ``layer`` is this layer's
+    index among them: the step writes only its new rows (or, for an SSM, its
+    state) into the stack, in place, and returns the stack; in prefill
+    ``cache`` is this layer's own slice. ``ssm_mask`` (B, S) marks valid
     prompt positions for pad-bucketed SSM prefill; attention mixers reject it
     (their positions are absolute, so left padding would shift rope).
     ``route_mask`` (B*S,) bool asks an expert layer for its per-expert
@@ -132,11 +138,13 @@ def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
         if mode == "decode":
             if cfg.use_mla:
                 mix, (ck, kr) = att.mla_decode(lp["attn"], h, cfg, cache["c_kv"],
-                                               cache["k_rope"], pos, impl=ctx.attn_impl)
+                                               cache["k_rope"], layer, pos,
+                                               impl=ctx.attn_impl)
                 new_cache.update(c_kv=ck, k_rope=kr)
             else:
                 mix, (ck, cv) = att.gqa_decode(lp["attn"], h, cfg, cache["k"], cache["v"],
-                                               pos, window=_window(cfg, kind), impl=ctx.attn_impl)
+                                               layer, pos, window=_window(cfg, kind),
+                                               impl=ctx.attn_impl)
                 new_cache.update(k=ck, v=cv)
         else:
             if cfg.use_mla:
@@ -175,8 +183,10 @@ def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
                 raise ValueError(
                     f"SSM decode is single-token; got {h.shape[1]} positions "
                     f"for layer kind {kind!r}")
-            mix, (conv_s, ssm_s) = step(lp["mixer"], h, cfg, cache["conv"], cache["ssm"])
-            new_cache.update(conv=conv_s, ssm=ssm_s)
+            mix, (conv_s, ssm_s) = step(lp["mixer"], h, cfg, cache["conv"][layer],
+                                        cache["ssm"][layer])
+            new_cache.update(conv=cache["conv"].at[layer].set(conv_s),
+                             ssm=cache["ssm"].at[layer].set(ssm_s))
         else:
             mix, (conv_s, ssm_s) = fwd(lp["mixer"], h, cfg, mask=ssm_mask)
             if mode == "prefill":
@@ -194,8 +204,8 @@ def apply_layer(lp, x, cfg, kind, mlp_kind, ctx, mode, cache, pos,
         if mode == "decode":
             # enc_len masks rows to their own encoder length when the cache
             # region is preallocated wider (slot pools); None = exact length
-            xo = att.gqa_cross(lp["cross"], hc, cfg, cache["xk"], cache["xv"],
-                               enc_len=enc_len, impl=ctx.attn_impl)
+            xo = att.gqa_cross(lp["cross"], hc, cfg, cache["xk"][layer],
+                               cache["xv"][layer], enc_len=enc_len, impl=ctx.attn_impl)
         else:
             ek, ev = att.cross_kv(lp["cross"], enc_out, cfg)
             xo = att.gqa_cross(lp["cross"], hc, cfg, ek, ev, impl=ctx.attn_impl)
@@ -263,29 +273,40 @@ def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
                 route_mask=None):
     """Returns (x, aux, caches, counts): ``counts`` is (expert layers, E)
     int32, each expert layer's assignments from the tokens ``route_mask``
-    marks, where a mask is given and the stack has expert layers; else None."""
+    marks, where a mask is given and the stack has expert layers; else None.
+
+    Prefill scans each stage's cache as ``xs`` and collects the written cache
+    as ``ys``. Decode carries the stage's stacked cache through the layer
+    loop instead, with the layer index as ``xs``: each layer scatters its new
+    rows into the carried stack (the buffer a jitted caller donates), so a
+    step writes only those rows and never copies the stack."""
     stages = compute_stages(cfg, cross=cross)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches, counts = [], []
+    carried = mode == "decode" and cache is not None
     for si, st in enumerate(stages):
         sp = stage_params[si]
         sc = cache[si] if cache is not None else None
 
         def body(carry, xs, _pattern=st.pattern):
-            xc, aux = carry
-            lp, cin = xs if sc is not None else (xs, None)
+            xc, aux, stack = carry
+            lp, cin, layer = xs
+            if carried:
+                cin = stack
             cout, cnt = {}, []
             for j, (kind, mlp) in enumerate(_pattern):
                 xc, a, cj, nj = apply_layer(
                     lp[f"l{j}"], xc, cfg, kind, mlp, ctx, mode,
-                    cin[f"l{j}"] if cin is not None else None, pos,
+                    cin[f"l{j}"] if cin is not None else None, pos, layer,
                     enc_out=enc_out, causal=not cross, enc_len=enc_len,
                     ssm_mask=ssm_mask, route_mask=route_mask)
                 aux = aux + a
                 cout[f"l{j}"] = cj
                 if nj is not None:
                     cnt.append(nj)
-            return (xc, aux), (cout if sc is not None else None, cnt)
+            if carried:
+                return (xc, aux, cout), (None, cnt)
+            return (xc, aux, None), (cout if cin is not None else None, cnt)
 
         if mode == "train":
             # remat policy is a partitioner/plan knob (§Perf): "full" remats
@@ -314,8 +335,8 @@ def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
             from repro.sharding.pipeline import circular_pipeline
 
             def stage_fn(group, xmb):
-                (y, a), _ = jax.lax.scan(
-                    fn, (xmb, jnp.zeros((), jnp.float32)), group)
+                (y, a, _), _ = jax.lax.scan(
+                    fn, (xmb, jnp.zeros((), jnp.float32), None), (group, None, None))
                 return y, a
 
             x, aux = circular_pipeline(stage_fn, sp, x, int(pipe["stages"]),
@@ -323,9 +344,12 @@ def apply_stack(stage_params, cfg, x, ctx, mode, cache=None, pos=0,
             aux_total = aux_total + aux
             new_caches.append(None)
             continue
-        xs = (sp, sc) if sc is not None else sp
-        (x, aux_total), (c_new, cnt) = jax.lax.scan(fn, (x, aux_total), xs)
-        new_caches.append(c_new)
+        if carried:
+            carry, xs = (x, aux_total, sc), (sp, None, jnp.arange(st.repeats))
+        else:
+            carry, xs = (x, aux_total, None), (sp, sc, None)
+        (x, aux_total, c_carried), (c_new, cnt) = jax.lax.scan(fn, carry, xs)
+        new_caches.append(c_carried if carried else c_new)
         counts += cnt  # each (repeats, E)
     return (x, aux_total, (new_caches if cache is not None else None),
             jnp.concatenate(counts) if counts else None)

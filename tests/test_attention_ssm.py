@@ -60,7 +60,9 @@ def test_mla_decode_absorbed_equals_naive():
     cache_kr = jnp.zeros((B, S + 4, cfg.qk_rope_dim))
     cache_ckv = cache_ckv.at[:, :S].set(c_kv)
     cache_kr = cache_kr.at[:, :S].set(k_rope)
-    out_dec, _ = mla_decode(p, x[:, S:S + 1], cfg, cache_ckv, cache_kr, jnp.int32(S))
+    # a stage's stacked cache of one layer, decoded at layer 0
+    out_dec, _ = mla_decode(p, x[:, S:S + 1], cfg, cache_ckv[None], cache_kr[None], 0,
+                            jnp.int32(S))
     np.testing.assert_allclose(np.asarray(out_dec[:, 0]), np.asarray(out_full[:, S]),
                                atol=2e-3, rtol=2e-3)
 

@@ -1,6 +1,6 @@
 """Compiles for a described TPU v5e, without a chip: the three Pallas kernels
-at the widths of the models they serve, and TinyLlama-1.1B's served prefill
-and decode at full width. What the chip's compiler refuses (block tiling,
+at the widths of the models they serve, TinyLlama-1.1B's served prefill
+and decode at full width, and the served decode's cache writes. What the chip's compiler refuses (block tiling,
 VMEM, a program that does not fit HBM) fails here at no chip time. Nothing
 runs, so these say nothing about results or speed.
 
@@ -8,8 +8,10 @@ The topology is described only inside the ``topo`` fixture, never while a
 module is imported: one process at a time may load the TPU library, and
 every test worker imports every test file.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +157,71 @@ def test_deepseek_stage_decode_compiles_for_v5e(one_chip):
         _on(one_chip, (64,), jnp.int32), None, _on(one_chip, (64,), jnp.bool_)).compile()
     _fits_one_chip(compiled)
     assert compiled.as_text().count("ragged-dot-none") >= 3 * 6
+
+
+_INSTR = re.compile(r"%([\w.\-]+) = \w+\[([\d,]*)\]\{([\d,]*)[^}]*\} ([\w\-]+)\(%?([\w.\-]*)")
+
+
+def _cache_writes(compiled, cache):
+    """Each ``copy`` and ``dynamic-update-slice`` of the compiled program whose
+    result has a cache leaf's stacked shape, or one layer's slice of it, as
+    (op, result shape, relayout): ``relayout`` marks a copy whose operand
+    has another layout, which reorders a cache for its reader rather than
+    copying its bytes."""
+    watched = set()
+    for leaf in jax.tree.leaves(cache):
+        watched |= {leaf.shape, leaf.shape[1:], (1,) + leaf.shape[1:]}
+    found, layout = [], {}
+    for name, dims, lay, op, operand in _INSTR.findall(compiled.as_text()):
+        layout[name] = lay
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if op in ("copy", "dynamic-update-slice") and shape in watched:
+            found.append((op, shape, op == "copy" and layout.get(operand, lay) != lay))
+    return found
+
+
+def _served_decode(one_chip, cfg, slots, max_len, counts=False):
+    """The worker's jitted decode (cache donated, (B,) positions) compiled
+    for one chip, and the cache's shapes."""
+    params = jax.eval_shape(functools.partial(init_params, cfg=cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype), params)
+    w = ModelWorker(cfg.name, cfg, params, max_len=max_len)
+    cache = _cache(w, one_chip, slots)
+    extra = (None, _on(one_chip, (slots,), jnp.bool_)) if counts else ()
+    compiled = w._decode.lower(
+        w.params, cache, _on(one_chip, (slots, 1), jnp.int32),
+        _on(one_chip, (slots,), jnp.int32), *extra).compile()
+    return compiled, cache
+
+
+def test_dense_decode_writes_only_new_rows_on_v5e(one_chip):
+    """Granite's widths, 4 layers in one scanned stage, 32 slots x 768: the
+    donated pool is written by the row scatter alone, in place. No copy or
+    dynamic-update-slice of the whole stack or of one layer's slice is left,
+    save the relayout of the slice the attention dot reads (heads before
+    positions), and the program's temporaries stay under one layer's K."""
+    cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=4)
+    compiled, cache = _served_decode(one_chip, cfg, 32, 768)
+    k = cache[0]["l0"]["k"]
+    writes = _cache_writes(compiled, cache)
+    assert writes and all(op == "copy" and shape != k.shape and relayout
+                          for op, shape, relayout in writes), writes
+    one_layer_k = k.size // k.shape[0] * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer_k
+
+
+def test_mla_expert_decode_writes_only_new_rows_on_v5e(one_chip):
+    """DeepSeek-V2-Lite's widths, 2 latent-attention expert layers in one
+    scanned stage, 64 slots x 1536, with routing counts: no
+    dynamic-update-slice of the latent caches, no copy of the latent stack
+    and no copy of any cache's bytes, whole or per layer. The copies left
+    are relayouts: of the slices the attention reads, and of the 64-wide
+    rope-key stack between the device's layout (positions minor) and the
+    row scatter's."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), num_layers=2,
+                              first_dense_layers=0)
+    compiled, cache = _served_decode(one_chip, cfg, 64, 1536, counts=True)
+    c_kv = cache[0]["l0"]["c_kv"]
+    writes = _cache_writes(compiled, cache)
+    assert writes and all(op == "copy" and shape != c_kv.shape and relayout
+                          for op, shape, relayout in writes), writes
