@@ -154,7 +154,7 @@ class AdaOperController:
                             ) -> Tuple[float, float, EnergyBreakdown]:
         """``run_inference`` with the ground-truth energy split per rail.
         Appends one ``infer`` StepEvent to the device ledger — the record
-        every downstream aggregate (fleet report, benchmarks) folds."""
+        every downstream aggregate (fleet report, examples) folds."""
         self._check_fault_epoch()
         if graph.name not in self.plans:
             self.plan(graph)

@@ -19,7 +19,7 @@ lambda-batched DP (``_dp_solve_batch`` over (L, A, P) tensors) instead of L
 sequential scalar DPs, and edge-cost tables are served from the profiler's
 ``CostTableCache`` when the cost callable exposes one. Both paths produce
 bit-identical plans (same ``argmin`` tie-breaking); ``vectorize=False``
-keeps the scalar reference alive for equivalence tests and benchmarks.
+keeps the scalar reference alive for the equivalence tests.
 
 Incremental re-partition: when runtime energy drifts on a segment of
 operators, only that segment is re-solved with its boundary placements

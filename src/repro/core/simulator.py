@@ -106,7 +106,7 @@ class DeviceSim:
                           if battery_capacity_j is not None else None)
         self.rng = np.random.default_rng(seed)
         # the device's telemetry spine: the controller and serving engine
-        # append StepEvents here; fleet reports and benchmarks fold it
+        # append StepEvents here; fleet reports fold it
         self.ledger = EnergyLedger()
         p = self.preset
         self.state = DeviceState(p["cpu_f"], p["gpu_f"], p["cpu_bg"], p["gpu_bg"])

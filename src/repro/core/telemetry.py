@@ -15,9 +15,9 @@ an :class:`EnergyLedger` instead of keeping private tallies:
     events for every engine iteration and a ``request`` event at retirement,
     with predicted energy split across rails by the partition plan's
     physics-derived fractions;
-  * ``repro.fleet.report`` and the ``benchmarks/bench_*.py`` entry points
-    *fold* the ledger — energy/request, battery drain, SLO attainment and
-    latency percentiles all trace back to these events.
+  * ``repro.fleet.report`` and the examples *fold* the ledger —
+    energy/request, battery drain, SLO attainment and latency percentiles
+    all trace back to these events.
 
 Conservation is testable: the per-rail components of every breakdown sum to
 the simulator's ground-truth joules (``tests/test_telemetry.py``), and the

@@ -11,8 +11,7 @@ serving mode, a :class:`ServingEngine`) — then replays a scenario trace from
 Backends:
   * ``graph``   — every request is one inference of its model's operator
     graph through ``AdaOperController.run_trace`` (ground-truth simulator
-    physics; fast; all scenarios). This is what ``benchmarks/bench_fleet.py``
-    and the CI smoke run.
+    physics; fast; all scenarios).
   * ``serving`` — LLM requests are served token-by-token through the
     continuous-batching ``ServingEngine`` (batched prefill admission,
     energy-aware admission, virtual clock) while vision frames run through

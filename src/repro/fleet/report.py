@@ -6,8 +6,8 @@ latency percentiles (p50/p95/p99, linear interpolation — the math is
 hand-verified in ``tests/test_fleet.py``). The records themselves are
 derived from the device's :class:`~repro.core.telemetry.EnergyLedger`
 (``repro.fleet.replay``), so every number here traces to one event stream.
-Serializes to/from the ``BENCH_fleet*.json`` schema gated by
-``benchmarks/run.py --smoke``.
+Serializes to and from JSON (``FleetReport.write_json`` / ``read_json``), so
+a replay's report can be saved and compared with another.
 """
 from __future__ import annotations
 

@@ -16,8 +16,9 @@ class ExecContext:
     batch_axes: Tuple[str, ...] = ()  # mesh axes sharding the batch dim
     model_axis: Optional[str] = None  # mesh axis sharding heads/ffn/experts
     attn_impl: str = "xla"  # "xla" | "pallas"
-    # partitioner-chosen per-layer-class overrides (AdaOper plan), e.g.
-    # {"moe": {"expert_parallel": False}} — populated by sharding.apply
+    # per-run layout switches the model code reads ("moe_2d",
+    # "attn_seq_shard", "remat_policy", "pipeline"); set by the caller,
+    # e.g. launch/dryrun.py's --plan
     plan: dict = field(default_factory=dict)
 
     @property

@@ -23,7 +23,7 @@ a model is attached) and calibrated online from the same feedback stream
   exactly once (the controller folds them into ``EnergyLedger`` counters).
 
 The profiler never imports this package — it is attached by callers
-(fleet replay, benchmarks, tests) and duck-typed, the same inert-by-default
+(fleet replay, tests) and duck-typed, the same inert-by-default
 discipline as the fault injector: unattached, every existing number is
 bit-identical and zero extra model evaluations happen.
 """

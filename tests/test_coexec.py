@@ -1,8 +1,7 @@
 """Contention-aware joint co-execution planning (repro.core.coexec,
 docs/coexec.md): simulator contention physics, the contention-priced cost
 wrapper, joint-vs-independent fallback bit-identity, ledger-feedback
-corrections, scheduler wiring, and the baseline regen-recipe derivation."""
-import json
+corrections, scheduler wiring, and the docs consistency checker."""
 import os
 
 import numpy as np
@@ -319,62 +318,6 @@ def test_scheduler_joint_plan_rescored_on_base(profiler):
     from repro.core import score_plan
     base = score_plan(g, ent.alphas, cost_fn)
     assert ent.pred_energy == base.pred_energy  # accounting on base predictor
-
-
-# ---------------------------------------------------------------------------
-# regen-recipe derivation (benchmarks.baseline_gate.fleet_regen_cmd)
-# ---------------------------------------------------------------------------
-
-
-def test_fleet_regen_cmd_derived_from_baseline_filename():
-    from benchmarks.baseline_gate import fleet_regen_cmd
-
-    cases = {
-        "benchmarks/baselines/BENCH_fleet.json": "--smoke-config",
-        "benchmarks/baselines/BENCH_fleet_serving.json":
-            "--serving-smoke-config",
-        "benchmarks/baselines/BENCH_fleet_chaos.json": "--chaos-smoke-config",
-        "benchmarks/baselines/BENCH_fleet_voice.json":
-            "--scenario-smoke-config voice",
-        "benchmarks/baselines/BENCH_fleet_video.json":
-            "--scenario-smoke-config video",
-    }
-    for path, flag in cases.items():
-        cmd = fleet_regen_cmd(path)
-        assert f" {flag} " in cmd, cmd
-        assert cmd.endswith(f"--json {path}"), cmd
-
-
-def test_gate_failure_message_names_the_gated_file(tmp_path):
-    """A chaos/scenario gate failure must echo the exact regeneration
-    command for the file it compared against — including the
-    --chaos-smoke-config / --scenario-smoke-config flags — regardless of
-    the failing run's own config."""
-    from benchmarks.baseline_gate import gate_fleet
-
-    def out_for(n):
-        return {"fleet": {"n_requests": n, "energy_per_request_j": 0.05,
-                          "slo_attainment": 1.0, "counters": {}}}
-
-    for name, flag in (("BENCH_fleet_chaos.json", "--chaos-smoke-config"),
-                       ("BENCH_fleet_voice.json",
-                        "--scenario-smoke-config voice")):
-        baseline = tmp_path / name
-        baseline.write_text(json.dumps(out_for(10)))
-        with pytest.raises(AssertionError) as exc:
-            gate_fleet(out_for(11), str(baseline))
-        msg = str(exc.value)
-        assert flag in msg, msg
-        assert f"--json {baseline}" in msg or name in msg
-
-
-def test_missing_baseline_recipe_names_the_missing_file(tmp_path):
-    from benchmarks.baseline_gate import gate_fleet
-
-    missing = tmp_path / "BENCH_fleet_video.json"
-    with pytest.raises(SystemExit) as exc:
-        gate_fleet({"fleet": {}}, str(missing))
-    assert "--scenario-smoke-config video" in str(exc.value)
 
 
 # ---------------------------------------------------------------------------

@@ -380,39 +380,6 @@ def test_unknown_model_error_names_request_uids():
 
 
 # ---------------------------------------------------------------------------
-# chaos gate wiring: every out-of-tolerance metric in ONE failure
-# ---------------------------------------------------------------------------
-
-
-def test_gate_fleet_reports_all_failures_at_once(tmp_path):
-    import json
-
-    from benchmarks.baseline_gate import gate_fleet
-
-    base = {"fleet": {"n_requests": 10, "energy_per_request_j": 1.0,
-                      "slo_attainment": 0.9, "counters": {"shed": 1}}}
-    path = tmp_path / "BENCH_x.json"
-    path.write_text(json.dumps(base))
-    out = {"fleet": {"n_requests": 11, "energy_per_request_j": 2.0,
-                     "slo_attainment": 0.5, "counters": {"shed": 3}}}
-    with pytest.raises(AssertionError) as exc:
-        gate_fleet(out, str(path), "regen-cmd", 0.25, 0.15,
-                   label="fleet[chaos]", counter_keys=("shed",))
-    msg = str(exc.value)
-    assert "4 gate failure(s)" in msg
-    assert "no longer deterministic" in msg
-    assert "energy/request drifted" in msg
-    assert "SLO attainment regressed" in msg
-    assert "counter 'shed' diverged: 3 vs baseline 1" in msg
-    assert "regen-cmd" in msg  # the fix stays copy-pasteable
-
-    ok = {"fleet": {"n_requests": 10, "energy_per_request_j": 1.1,
-                    "slo_attainment": 0.85, "counters": {"shed": 1}}}
-    gate_fleet(ok, str(path), "regen-cmd", 0.25, 0.15,
-               counter_keys=("shed",))  # within tolerance: no raise
-
-
-# ---------------------------------------------------------------------------
 # end-to-end: chaos replay + error Responses in fleet reports (jax)
 # ---------------------------------------------------------------------------
 

@@ -1,15 +1,9 @@
 """Fleet replay subsystem: trace determinism, population sampling,
 hand-computed aggregate math, replay determinism, serving backend."""
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
-
-# the benchmarks package lives at the repo root (same pattern as
-# test_benchmarks_smoke)
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from repro.fleet import (
     SCENARIOS,
@@ -188,21 +182,31 @@ def test_report_json_roundtrip(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _small_replay():
+def _small_replay(scenario="ar", **opts):
     pop = sample_population(1, seed=5)
-    return FleetReplay(pop, scenario="ar", duration_s=1.5, seed=5,
-                       calib_samples=120)
+    return FleetReplay(pop, scenario=scenario, duration_s=1.5, seed=5,
+                       calib_samples=120, **opts)
 
 
-def test_replay_graph_backend_deterministic_and_accounts():
-    rep_a = _small_replay().run()
-    rep_b = _small_replay().run()
+@pytest.mark.parametrize("scenario,opts", [
+    ("ar", {}),
+    ("ar", {"joint": True}),
+    ("ar", {"uncertainty": True}),
+    ("video", {}),
+], ids=["ar", "ar-joint", "ar-uncertainty", "video"])
+def test_replay_graph_backend_deterministic_and_accounts(scenario, opts):
+    rep_a = _small_replay(scenario, **opts).run()
+    rep_b = _small_replay(scenario, **opts).run()
     assert rep_a.to_dict() == rep_b.to_dict()
     f = rep_a.fleet
-    assert f["n_requests"] > 0
+    # every arrival is served: joint planning and the interval model change
+    # plans, never which requests are served
+    assert f["n_requests"] == len(make_trace(scenario, 1.5, seed=5)) > 0
     assert f["energy_per_request_j"] > 0.0
     assert f["battery_drain_pct_mean"] > 0.0  # replay drains the battery
     assert 0.0 <= f["slo_attainment"] <= 1.0
+    # interval coverage reaches the report exactly when a model is attached
+    assert ("interval_coverage" in f) == bool(opts.get("uncertainty"))
     d = rep_a.devices[0]
     assert d.battery_end_pct < d.battery_start_pct
     assert d.latency_s["p50"] <= d.latency_s["p95"] <= d.latency_s["p99"]
@@ -332,35 +336,3 @@ def test_serving_backend_rejects_model_unknown_to_both_registries():
                          serving_models={ASSISTANT: (cfg, params)})
     with pytest.raises(ValueError, match="neither a serving worker nor"):
         replay.run()
-
-
-# ---------------------------------------------------------------------------
-# baseline gate ergonomics
-# ---------------------------------------------------------------------------
-
-
-def test_missing_baseline_fails_with_regeneration_recipe(tmp_path):
-    from benchmarks.baseline_gate import load_baseline
-
-    missing = str(tmp_path / "BENCH_nope.json")
-    with pytest.raises(SystemExit) as exc:
-        load_baseline(missing, "python -m benchmarks.bench_fleet --regen")
-    msg = str(exc.value)
-    assert "BENCH_nope.json" in msg
-    assert "python -m benchmarks.bench_fleet --regen" in msg  # copy-pasteable
-
-
-def test_corrupt_baseline_fails_clearly(tmp_path):
-    from benchmarks.baseline_gate import load_baseline
-
-    bad = tmp_path / "BENCH_bad.json"
-    bad.write_text("{not json")
-    with pytest.raises(SystemExit, match="unreadable"):
-        load_baseline(str(bad), "regen-cmd")
-
-
-def test_fleet_gate_uses_loud_baseline_error(tmp_path):
-    from benchmarks import bench_fleet
-
-    with pytest.raises(SystemExit, match="Regenerate"):
-        bench_fleet.gate({"fleet": {}}, str(tmp_path / "missing.json"))

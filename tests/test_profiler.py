@@ -72,8 +72,7 @@ def test_profiler_feedback_improves_under_latent_drift():
     ada = RuntimeEnergyProfiler(use_gru=True)
     ada.offline_calibrate([g], n_samples=1500, seed=1)
     # fixed scenario seed: burst phasing is stochastic and the GRU needs the
-    # thermal residual to dominate the window (benchmarks/bench_profiler.py
-    # reports the multi-seed quantitative version: +59% at high load)
+    # thermal residual to dominate the window
     sim = DeviceSim("high", seed=11)
     sim._therm = 1.0  # sustained-load hot device
     for it in range(160):

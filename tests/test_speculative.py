@@ -19,7 +19,7 @@ from repro.configs.base import get_config, reduced
 from repro.core import DeviceSim, RuntimeEnergyProfiler, build_transformer_graph
 from repro.core.telemetry import fold_energy
 from repro.models import init_params
-from repro.serving import sampling, speculative
+from repro.serving import sampling
 from repro.serving.engine import AdaOperScheduler, Request, ServingEngine
 from repro.serving.speculative import SpecConfig, truncated_draft
 
